@@ -7,49 +7,57 @@ sweep for each, plus a correctness gate — the sharded chain must be
 BITWISE identical to the single-device chain (the counter-based-RNG
 contract the plane is built on).
 
-The sharded timing runs in a subprocess (virtual devices must be
-configured before jax initializes; the bench driver process is already
-single-device), which re-emits its rows through this process's sink.
+The sharded half runs in this process when it sees at least four devices.
+Otherwise it runs in a child that is CPU-only (``JAX_PLATFORMS=cpu``, four
+virtual devices): a chip belongs to the process that touched JAX first, so
+the child never asks for it. The child re-emits its rows through this
+process's sink.
 """
 from __future__ import annotations
 
 import os
 import subprocess
 import sys
-import textwrap
 import time
 from pathlib import Path
 
 from benchmarks.common import emit
 
-_SUBPROC = """
-import time
-import jax, jax.numpy as jnp
-from repro.core import ising3d as I3
-from repro.distributed import ising3d as d3
-from repro.launch import mesh as mesh_lib
-
-side, n_sweeps, beta = {side}, {n_sweeps}, {beta}
-key = jax.random.PRNGKey(0)
-full = I3.random_lattice3d(jax.random.PRNGKey(1), side, side, side)
-
-mesh = mesh_lib.make_mesh((2, 2), ("data", "model"))
-cfg = d3.Dist3DConfig(beta=beta, row_axes=("data",), col_axes=("model",))
-run = d3.make_run_sweeps_fn(mesh, cfg, n_sweeps)
-sh = d3.lattice_sharding(mesh, cfg)
-
-out = jax.block_until_ready(run(jax.device_put(full, sh), key))  # compile
-t0 = time.perf_counter()
-out = jax.block_until_ready(run(jax.device_put(full, sh), key))
-secs = time.perf_counter() - t0
-
-want, _ = I3.run_sweeps3d(full, key, n_sweeps, beta)
-bitwise = bool((jax.device_get(out) == jax.device_get(want)).all())
-msites = side ** 3 * n_sweeps / secs / 1e6
-print(f"ROW,mesh3d_sharded_2x2_{{side}},{{secs / n_sweeps:.9f}},"
-      f"Msites_per_s={{msites:.2f}} bitwise_eq_single={{bitwise}}")
-assert bitwise, "sharded 3-D chain diverged from single device"
+_CHILD = """
+from benchmarks.mesh3d import sharded_row
+print("ROW," + ",".join(map(str, sharded_row({side}, {n_sweeps}, {beta!r},
+                                              {seed}))))
 """
+
+
+def sharded_row(side: int, n_sweeps: int, beta: float, seed: int) -> tuple:
+    """Time the cube sharded over a 2x2 device grid; fails unless the chain
+    is bitwise equal to the single-device one. Returns an ``emit`` row."""
+    import jax
+    from repro.core import ising3d as I3
+    from repro.distributed import ising3d as d3
+    from repro.launch import mesh as mesh_lib
+
+    key = jax.random.PRNGKey(seed)
+    full = I3.random_lattice3d(jax.random.PRNGKey(seed + 1),
+                               side, side, side)
+    mesh = mesh_lib.make_mesh((2, 2), ("data", "model"))
+    cfg = d3.Dist3DConfig(beta=beta, row_axes=("data",),
+                          col_axes=("model",))
+    run = d3.make_run_sweeps_fn(mesh, cfg, n_sweeps)
+    sh = d3.lattice_sharding(mesh, cfg)
+
+    jax.block_until_ready(run(jax.device_put(full, sh), key))  # compile
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(run(jax.device_put(full, sh), key))
+    secs = time.perf_counter() - t0
+
+    want, _ = I3.run_sweeps3d(full, key, n_sweeps, beta)
+    if not bool((jax.device_get(out) == jax.device_get(want)).all()):
+        raise AssertionError("sharded 3-D chain diverged from single device")
+    msites = side ** 3 * n_sweeps / secs / 1e6
+    return (f"mesh3d_sharded_2x2_{side}", secs / n_sweeps,
+            f"Msites_per_s={msites:.2f} bitwise_eq_single=True")
 
 
 def run(side=32, n_sweeps=20, smoke=False, seed=0):
@@ -74,14 +82,17 @@ def run(side=32, n_sweeps=20, smoke=False, seed=0):
     emit(f"mesh3d_single_{side}", secs / n_sweeps,
          f"Msites_per_s={side ** 3 * n_sweeps / secs / 1e6:.2f}")
 
-    # -- sharded 2x2 (subprocess: device count is locked at jax init) ------
+    # -- sharded 2x2 -------------------------------------------------------
+    if len(jax.devices()) >= 4:
+        emit(*sharded_row(side, n_sweeps, beta, seed))
+        return 0
     repo = Path(__file__).resolve().parent.parent
     env = dict(os.environ)
-    env["PYTHONPATH"] = (str(repo / "src") + os.pathsep
-                         + env.get("PYTHONPATH", ""))
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(repo / "src"), str(repo), env.get("PYTHONPATH", "")])
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    code = textwrap.dedent(_SUBPROC.format(side=side, n_sweeps=n_sweeps,
-                                           beta=beta))
+    code = _CHILD.format(side=side, n_sweeps=n_sweeps, beta=beta, seed=seed)
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=900)
     if proc.returncode != 0:

@@ -72,6 +72,7 @@ for arch, shape in cells:
 
 def run_fallback():
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"   # never the chip a parent may hold
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     p = subprocess.run([sys.executable, "-c", textwrap.dedent(_FALLBACK)],

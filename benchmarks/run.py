@@ -76,6 +76,8 @@ def main(argv=None) -> int:
         ap.error(f"unknown sections {sorted(unknown)}; choose from "
                  f"{SECTIONS}")
     wanted = set(args.sections) or set(SECTIONS)
+    from repro import compile_cache
+    compile_cache.enable()
 
     rc = 0
     for name in SECTIONS:

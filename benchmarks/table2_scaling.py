@@ -11,7 +11,9 @@ meaningless — instead we verify the two things the container CAN measure:
      (parsed from the compiled HLO) — the structural property that produces
      the paper's linear scaling on real interconnects.
 
-Run in a subprocess per mesh size (jax locks the device count per process).
+Run in a CPU-only subprocess per mesh size (jax locks the device count per
+process, and a child must never ask for a chip its parent holds). A failed
+child fails the run.
 """
 from __future__ import annotations
 
@@ -60,12 +62,13 @@ print("RESULT=" + json.dumps({
 
 
 def run(meshes=((1, 2), (2, 2), (2, 4), (2, 2, 2), (2, 2, 4))):
-    rows = []
+    rows, failed = [], []
     for shape in meshes:
         n = 1
         for x in shape:
             n *= x
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
         env["MESH_SHAPE"] = json.dumps(list(shape))
         env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
@@ -75,6 +78,7 @@ def run(meshes=((1, 2), (2, 2), (2, 4), (2, 2, 2), (2, 2, 4))):
         if p.returncode != 0:
             emit(f"table2_mesh_{'x'.join(map(str, shape))}", 0.0,
                  f"FAILED: {p.stderr[-200:]}")
+            failed.append(shape)
             continue
         line = [l for l in p.stdout.splitlines() if l.startswith("RESULT=")][0]
         r = json.loads(line[len("RESULT="):])
@@ -92,6 +96,8 @@ def run(meshes=((1, 2), (2, 2), (2, 4), (2, 2, 2), (2, 2, 4))):
         ratio = wN / max(w0, 1e-9)
         emit("table2_weak_scaling_wire_ratio", 0.0,
              f"last_over_first={ratio:.3f} (linear scaling iff ~<=1.0)")
+    if failed:
+        raise RuntimeError(f"table2 children failed for meshes {failed}")
     return rows
 
 

@@ -76,7 +76,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core import checkerboard as cb
 from repro.core import ising3d as I3
 from repro.core import lattice as L
@@ -84,6 +83,7 @@ from repro.core import measure
 from repro.core import observables as obs
 from repro.core import sampler
 from repro.core import tempering as pt
+from repro.kernels import ops as kops
 
 _BACKENDS = ("xla", "pallas", "pallas_lines", "ref")
 _TOPOLOGIES = ("single", "mesh")
@@ -127,8 +127,6 @@ class EngineConfig:
     dtype: str = "bfloat16"
     prob_dtype: str = "float32"
     block_size: int = 0                # 0 -> min(128, size // 2)
-    interpret: Optional[bool] = None   # Pallas interpret mode; None -> auto
-                                       # (False on TPU, True elsewhere)
     measure: bool = True               # stream per-sweep (m, E) + moments
     measure_every: int = 1             # moment-accumulation thinning cadence
     field: float = 0.0                 # external field h (2-D xla only)
@@ -514,7 +512,8 @@ class IsingEngine:
                              or (cfg.pipeline == "opt"
                                  and cfg.topology == "single")):
             shape = cfg.mesh_shape or (1,) * len(cfg.mesh_axes)
-            self.mesh = compat.make_mesh(shape, cfg.mesh_axes)
+            from repro.launch.mesh import make_mesh
+            self.mesh = make_mesh(shape, cfg.mesh_axes)
         if self.mesh is not None and cfg.topology == "mesh":
             if cfg.betas:
                 n_shards = 1
@@ -678,7 +677,17 @@ class IsingEngine:
         replica reproduces it end to end. Hot/cold starts resolve per
         replica when ``hot=None`` (hot above Tc, cold below — the standard
         burn-in trick on both sides of the transition).
+
+        A sharded state is built by one jitted program whose output is
+        already sharded, so no device ever holds the global lattice; the
+        values are those of the eager single-device construction.
         """
+        sharding = self.state_sharding()
+        if sharding is None:
+            return self._build_state(key)
+        return jax.jit(self._build_state, out_shardings=sharding)(key)
+
+    def _build_state(self, key: jax.Array) -> jax.Array:
         c = self.cfg
         dt = jnp.dtype(c.dtype)
         scen = self._scenario()
@@ -686,12 +695,9 @@ class IsingEngine:
             return self._init_potts(key)
         if scen in ("3d", "mesh3d"):
             n = c.size
-            full = (I3.random_lattice3d(key, n, n, n, dt)
+            return (I3.random_lattice3d(key, n, n, n, dt)
                     if self._auto_hot(c.beta)
                     else I3.cold_lattice3d(n, n, n, dt))
-            if scen == "mesh3d":
-                full = jax.device_put(full, self.state_sharding())
-            return full
         if scen in ("ensemble", "tempering") or (scen == "cluster"
                                                  and c.betas):
             states = [
@@ -699,20 +705,13 @@ class IsingEngine:
                                    c.resolved_width(), dt,
                                    hot=self._auto_hot(b))
                 for i, b in enumerate(c.betas)]
-            state = jnp.stack(states)
-            if self.mesh is not None and c.topology == "mesh":
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                state = jax.device_put(state, NamedSharding(
-                    self.mesh, P(c.replica_axes, None, None, None)))
-            return state
+            return jnp.stack(states)
         if scen in ("mesh", "opt", "cluster_mesh"):
             w = c.resolved_width()
             full = (L.random_lattice(key, c.size, w, dt)
                     if self._auto_hot(c.beta) else L.cold_lattice(c.size, w, dt))
-            quads = L.to_quads(full)
-            bs = c.resolved_block_size()
-            qb = jnp.stack([L.block(quads[i], bs) for i in range(4)])
-            return jax.device_put(qb, self.lattice_sharding())
+            return kops._block_quads(L.to_quads(full),
+                                     c.resolved_block_size())
         return sampler.init_state(key, c.size, c.resolved_width(), dt,
                                   hot=self._auto_hot(c.beta))
 
@@ -733,14 +732,9 @@ class IsingEngine:
             return jnp.stack([one(jax.random.fold_in(key, i), b)
                               for i, b in enumerate(c.betas)])
         full = one(key, c.beta)
-        if c.topology == "mesh":
-            if c.algorithm == "metropolis":   # checkerboard: full view
-                return jax.device_put(full, self.state_sharding())
-            quads = L.to_quads(full)
-            bs = c.resolved_block_size()
-            qb = jnp.stack([L.block(quads[i], bs) for i in range(4)])
-            return jax.device_put(qb, self.lattice_sharding())
-        return full
+        if c.topology == "mesh" and c.algorithm != "metropolis":
+            return kops._block_quads(L.to_quads(full), c.resolved_block_size())
+        return full   # checkerboard keeps the full view, sharded or not
 
     # ------------------------------------------------------------------
     # Compiled runners (cached per engine)
@@ -798,19 +792,15 @@ class IsingEngine:
         nn recompute per sweep instead of the old per-sweep
         ``_unblock_quads`` + ``from_quads`` + roll reconstruction.
         """
-        from repro.kernels import ops as kops
         c = self.cfg
         bs = c.resolved_block_size()
         rule = c.kernel_rule()
-        interpret = (jax.default_backend() != "tpu" if c.interpret is None
-                     else c.interpret)
 
         def run(state, key):
             if not c.measure:
                 final = kops.run_sweeps(state, key, n_sweeps=c.n_sweeps,
                                         beta=c.beta, bs=bs,
-                                        backend=c.backend,
-                                        interpret=interpret, rule=rule)
+                                        backend=c.backend, rule=rule)
                 return final, None, None
 
             def body(carry, step):
@@ -818,8 +808,7 @@ class IsingEngine:
                 for color in (0, 1):
                     bits = kops.color_bits(key, step, color, qb.shape[1:])
                     qb = kops.update_color(qb, bits, c.beta, color,
-                                           backend=c.backend,
-                                           interpret=interpret, rule=rule)
+                                           backend=c.backend, rule=rule)
                 return qb, measure.blocked_stats(qb)
 
             qb0 = kops._block_quads(state, bs)

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.cluster import bonds as B
 from repro.cluster import label as LBL
 from repro.core import lattice as L

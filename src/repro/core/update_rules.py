@@ -64,8 +64,11 @@ _X_VALUES = (-4.0, -2.0, 0.0, 2.0, 4.0)
 
 
 def bits_to_uniform(bits: jax.Array) -> jax.Array:
-    """uint32 -> f32 uniform in [0, 1): keep the top 24 bits (exact in f32)."""
-    return (bits >> 8).astype(jnp.float32) * _INV_2_24
+    """uint32 -> f32 uniform in [0, 1): keep the top 24 bits (exact in f32).
+
+    The shifted value is below 2^24, so the detour through int32 is exact;
+    the TPU lowering has no direct uint32 -> f32 cast."""
+    return (bits >> 8).astype(jnp.int32).astype(jnp.float32) * _INV_2_24
 
 
 def _select5(x: jax.Array, t) -> jax.Array:
@@ -287,8 +290,8 @@ def _heat_bath_kernel_form(beta: float):
     def draw(sigma, nn, bits):
         p_up = _select5(nn, t)                     # keyed on nn, not sigma*nn
         up = bits_to_uniform(bits) < p_up
-        one = jnp.ones((), sigma.dtype)
-        return jnp.where(up, one, -one)
+        # select in f32, then cast: Mosaic cannot relayout a bf16 select
+        return jnp.where(up, 1.0, -1.0).astype(sigma.dtype)
 
     return draw
 

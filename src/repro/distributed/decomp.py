@@ -35,7 +35,7 @@ import jax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import measure
 
 

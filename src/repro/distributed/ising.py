@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import checkerboard as cb
 from repro.core import lattice as L
 from repro.core import measure
@@ -120,8 +120,8 @@ def _local_color_update(quads, key, step, color, cfg, edges,
     if cfg.backend == "pallas_lines":
         bits = jax.random.bits(k, (2,) + blk, jnp.uint32)
         out = kops.update_color(jnp.stack(quads), bits, cfg.beta, color,
-                                backend="pallas_lines", interpret=True,
-                                edges=edges, rule=cfg.bits_rule())
+                                backend="pallas_lines", edges=edges,
+                                rule=cfg.bits_rule())
         out = tuple(out[i] for i in range(4))
         return (out, None) if return_stats else out
     kh = L.kernel_compact(a.shape[-1], a.dtype)
@@ -262,8 +262,7 @@ def make_sweep_with_bits_fn(mesh, cfg: DistIsingConfig):
         edges = halo.halo_edges(cfg.row_axes, cfg.col_axes, nrows, ncols)
         for color in (0, 1):
             qb = kops.update_color(qb, bits[color], cfg.beta, color,
-                                   backend="pallas_lines", interpret=True,
-                                   edges=edges)
+                                   backend="pallas_lines", edges=edges)
         return qb
 
     mapped = shard_map(local_sweep, mesh=mesh, check_vma=False,

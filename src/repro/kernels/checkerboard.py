@@ -5,28 +5,28 @@ it performs, entirely in VMEM:
 
   * 4 MXU matmuls against the bidiagonal kernel K-hat (the paper's trick that
     moves the neighbour-sum stencil onto the matrix unit),
-  * halo compensation rows/cols read from the neighbouring blocks (fetched by
-    passing the passive quads again with torus-shifted ``index_map``s — no
-    extra HBM copies, the pipeline just streams the neighbour tiles),
+  * halo compensation rows/cols read from the neighbouring blocks and added
+    under ``broadcasted_iota`` masks (the TPU lowering has no scatter-add),
   * acceptance via a compile-time 5-entry LUT (sigma*nn in {-4,-2,0,2,4}; the
     paper uses exp(), the LUT is exact and avoids the transcendental),
   * uniform generation from raw uint32 bits and the Metropolis flip.
 
-RNG note: on real TPUs the bits input disappears — seed once with
-``pltpu.prng_seed(seed ^ program_id)`` and draw ``pltpu.prng_random_bits``
-in-kernel so uniforms never touch HBM. Those primitives have no CPU
-interpret-mode lowering (verified on jax 0.8.2), so the validated path takes
-counter-based ``jax.random.bits`` as an operand; flip ``USE_INKERNEL_PRNG``
-on TPU.
+RNG: the bits arrive as an operand drawn by counter-addressed
+``jax.random.bits`` outside the kernel, so the kernel is bitwise equal to
+the pure-jnp oracle in :mod:`repro.kernels.ref` on every backend. The TPU's
+stateful in-kernel generator (``pltpu.prng_random_bits``) would break that
+contract; generating the bits in-kernel needs a hash of the global site index
+instead.
 
 Block layout: quads arrive blocked ``[mr, mc, bs, bs]`` with ``bs=128``
-(MXU-native). VMEM per grid cell at bs=128: 12 bf16 tiles + 2 uint32 tiles
-~ 0.66 MB — far under the ~16 MB VMEM budget; bs=256 also fits (tunable).
+(MXU-native). Halo lines travel as ``[mr, mc, 1, bs]`` rows, so every block
+meets the TPU's (8, 128) tiling rule. On a TPU the kernels compile through
+Mosaic; everywhere else they run in the Pallas interpreter
+(:func:`interpret_mode`).
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -34,47 +34,28 @@ from jax.experimental import pallas as pl
 
 from repro.core import update_rules
 
-USE_INKERNEL_PRNG = False  # flip on real TPU; see module docstring
 
-VMEM_BYTES = 16 * 1024 * 1024  # v5e VMEM per core
-
-
-def vmem_bytes_per_cell(bs: int, lattice_bytes: int = 2,
-                        variant: str = "lines",
-                        double_buffered: bool = True) -> int:
-    """Static VMEM footprint of one grid cell of the checkerboard kernel.
-
-    lines variant: 4 spin tiles in (s0, s1, p0, p1) + K-hat + 2 uint32 bit
-    tiles + 4 boundary lines + 2 spin tiles out. The Pallas pipeline keeps
-    two buffers per operand in flight (double buffering), hence x2.
-    Used by tests to assert the shipped block sizes respect the budget —
-    this is the reasoning the BlockSpecs encode (module docstring).
-    """
-    tiles_spin = 4 + 1 + 2                  # in + kernel + out, bf16-ish
-    if variant == "tiles":
-        tiles_spin += 4                     # neighbour tiles fetched again
-    spin = tiles_spin * bs * bs * lattice_bytes
-    bits = 2 * bs * bs * 4                  # uint32 random bits
-    lines = 4 * bs * lattice_bytes
-    total = spin + bits + lines
-    return total * (2 if double_buffered else 1)
-
-_INV_2_24 = 1.0 / float(1 << 24)
-
-# The flip math is owned by repro.core.update_rules (compile-time
-# ``kernel_form``); these names remain as the kernel's historical API.
-_bits_to_uniform = update_rules.bits_to_uniform
+def interpret_mode() -> bool:
+    """True where Pallas must interpret the kernels: any backend but a TPU."""
+    return jax.default_backend() != "tpu"
 
 
-def _lut_acceptance(x, beta):
-    """exp(-2*beta*x) for x = sigma*nn in {-4,-2,0,2,4}; compile-time table
-    as a select chain (cheaper than a gather on the VPU, exact)."""
-    t = [math.exp(-2.0 * beta * v) for v in (-4.0, -2.0, 0.0, 2.0, 4.0)]
-    return update_rules._select5(x, t)
+def _add_row(nn, line, i: int):
+    """``nn`` with the ``[1, bs]`` halo ``line`` added to row ``i``."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, nn.shape, 0)
+    return nn + jnp.where(rows == i, line.astype(jnp.float32), 0.0)
 
 
-def _metropolis(sigma, nn, bits, beta):
-    return update_rules.metropolis_lut.kernel_form(beta)(sigma, nn, bits)
+def _add_col(nn, col, j: int):
+    """``nn`` with the halo column ``col`` (``[bs, 1]``) added to column
+    ``j``."""
+    cols = jax.lax.broadcasted_iota(jnp.int32, nn.shape, 1)
+    return nn + jnp.where(cols == j, col.astype(jnp.float32), 0.0)
+
+
+def _row_to_col(line, shape):
+    """A ``[1, bs]`` line as a column: broadcast over rows, transposed."""
+    return jnp.broadcast_to(line, shape).T
 
 
 def _update_kernel(s0_ref, s1_ref,
@@ -94,32 +75,40 @@ def _update_kernel(s0_ref, s1_ref,
 
     p0a/p1a are the row-shifted (north/south) neighbour tiles, p0b/p1b the
     col-shifted (west/east) ones — which shift is which depends on colour and
-    is wired up by the index maps in :func:`update_color_pallas`.
+    is wired up by the index maps in :func:`update_color_pallas`. Edge lines
+    are read with static non-negative indices.
     """
     kh = kh_ref[0, 0]
     kht = kh.T
     p0 = p0_ref[0, 0]
     p1 = p1_ref[0, 0]
     f32 = jnp.float32
+    last = kh.shape[0] - 1
+
+    def row(ref, i):                       # row i of a tile, [1, bs]
+        return ref[0, 0, pl.ds(i, 1), :]
+
+    def col(ref, j):                       # column j of a tile, [bs, 1]
+        return ref[0, 0, :, pl.ds(j, 1)]
 
     if color == 0:  # black: p0=B, p1=C
         nn0 = (jnp.dot(p0, kh, preferred_element_type=f32)
                + jnp.dot(kht, p1, preferred_element_type=f32))
-        nn0 = nn0.at[:, 0].add(p0b_ref[0, 0, :, -1].astype(f32))   # B west
-        nn0 = nn0.at[0, :].add(p1a_ref[0, 0, -1, :].astype(f32))   # C north
+        nn0 = _add_col(nn0, col(p0b_ref, last), 0)       # B west
+        nn0 = _add_row(nn0, row(p1a_ref, last), 0)       # C north
         nn1 = (jnp.dot(kh, p0, preferred_element_type=f32)
                + jnp.dot(p1, kht, preferred_element_type=f32))
-        nn1 = nn1.at[-1, :].add(p0a_ref[0, 0, 0, :].astype(f32))   # B south
-        nn1 = nn1.at[:, -1].add(p1b_ref[0, 0, :, 0].astype(f32))   # C east
+        nn1 = _add_row(nn1, row(p0a_ref, 0), last)       # B south
+        nn1 = _add_col(nn1, col(p1b_ref, 0), last)       # C east
     else:           # white: p0=A, p1=D
         nn0 = (jnp.dot(p0, kht, preferred_element_type=f32)
                + jnp.dot(kht, p1, preferred_element_type=f32))
-        nn0 = nn0.at[:, -1].add(p0b_ref[0, 0, :, 0].astype(f32))   # A east
-        nn0 = nn0.at[0, :].add(p1a_ref[0, 0, -1, :].astype(f32))   # D north
+        nn0 = _add_col(nn0, col(p0b_ref, 0), last)       # A east
+        nn0 = _add_row(nn0, row(p1a_ref, last), 0)       # D north
         nn1 = (jnp.dot(kh, p0, preferred_element_type=f32)
                + jnp.dot(p1, kh, preferred_element_type=f32))
-        nn1 = nn1.at[-1, :].add(p0a_ref[0, 0, 0, :].astype(f32))   # A south
-        nn1 = nn1.at[:, 0].add(p1b_ref[0, 0, :, -1].astype(f32))   # D west
+        nn1 = _add_row(nn1, row(p0a_ref, 0), last)       # A south
+        nn1 = _add_col(nn1, col(p1b_ref, last), 0)       # D west
 
     flip = update_rules.get_rule(rule).kernel_form(beta)
     out0_ref[0, 0] = flip(s0_ref[0, 0], nn0, bits0_ref[0, 0])
@@ -132,34 +121,33 @@ def _update_kernel_lines(s0_ref, s1_ref, p0_ref, p1_ref, kh_ref,
                          out0_ref, out1_ref, *, color: int, beta: float,
                          rule: str = "metropolis_lut"):
     """Edge-lines variant: halo lines are precomputed outside the kernel
-    ([mr, mc, bs] arrays), so each passive quad tile is streamed from HBM
-    exactly once (the tile-fetch variant reads them 3x). Beyond-paper
-    optimization — see EXPERIMENTS.md §Perf.
+    (``[mr, mc, 1, bs]`` arrays), so each passive quad tile is streamed from
+    HBM exactly once (the tile-fetch variant reads them 3x).
     """
     kh = kh_ref[0, 0]
     kht = kh.T
     p0 = p0_ref[0, 0]
     p1 = p1_ref[0, 0]
     f32 = jnp.float32
-    r0 = row0_ref[0, 0].astype(f32)
-    c0 = col0_ref[0, 0].astype(f32)
-    r1 = row1_ref[0, 0].astype(f32)
-    c1 = col1_ref[0, 0].astype(f32)
+    last = kh.shape[0] - 1
+    r0, r1 = row0_ref[0, 0], row1_ref[0, 0]
+    c0 = _row_to_col(col0_ref[0, 0], p0.shape)
+    c1 = _row_to_col(col1_ref[0, 0], p0.shape)
 
     if color == 0:  # p0=B, p1=C -> nn(A), nn(D)
         nn0 = (jnp.dot(p0, kh, preferred_element_type=f32)
                + jnp.dot(kht, p1, preferred_element_type=f32))
-        nn0 = nn0.at[0, :].add(r0).at[:, 0].add(c0)
+        nn0 = _add_col(_add_row(nn0, r0, 0), c0, 0)
         nn1 = (jnp.dot(kh, p0, preferred_element_type=f32)
                + jnp.dot(p1, kht, preferred_element_type=f32))
-        nn1 = nn1.at[-1, :].add(r1).at[:, -1].add(c1)
+        nn1 = _add_col(_add_row(nn1, r1, last), c1, last)
     else:           # p0=A, p1=D -> nn(B), nn(C)
         nn0 = (jnp.dot(p0, kht, preferred_element_type=f32)
                + jnp.dot(kht, p1, preferred_element_type=f32))
-        nn0 = nn0.at[0, :].add(r0).at[:, -1].add(c0)
+        nn0 = _add_col(_add_row(nn0, r0, 0), c0, last)
         nn1 = (jnp.dot(kh, p0, preferred_element_type=f32)
                + jnp.dot(p1, kh, preferred_element_type=f32))
-        nn1 = nn1.at[-1, :].add(r1).at[:, 0].add(c1)
+        nn1 = _add_col(_add_row(nn1, r1, last), c1, 0)
 
     flip = update_rules.get_rule(rule).kernel_form(beta)
     out0_ref[0, 0] = flip(s0_ref[0, 0], nn0, bits0_ref[0, 0])
@@ -167,8 +155,7 @@ def _update_kernel_lines(s0_ref, s1_ref, p0_ref, p1_ref, kh_ref,
 
 
 def update_color_pallas_lines(quads_blocked, bits, kh, beta: float, color: int,
-                              interpret: bool = True, edges=None,
-                              rule: str = "metropolis_lut"):
+                              edges=None, rule: str = "metropolis_lut"):
     """Edge-lines kernel wrapper. ``edges(xb, side) -> [mr, mc, bs]`` supplies
     halo lines (default: single-device torus rolls). Distributed samplers pass
     the ppermute-based provider — the kernel itself is distribution-agnostic.
@@ -180,12 +167,13 @@ def update_color_pallas_lines(quads_blocked, bits, kh, beta: float, color: int,
     _, mr, mc, bs, _ = quads_blocked.shape
     dtype = quads_blocked.dtype
 
-    row0, col0, row1, col1 = cb.edge_lines(a, b, c, d, color, edges)
+    lines = [x.reshape(mr, mc, 1, bs)
+             for x in cb.edge_lines(a, b, c, d, color, edges)]
     s0, s1 = (a, d) if color == 0 else (b, c)
     p0, p1 = (b, c) if color == 0 else (a, d)
 
     tile = pl.BlockSpec((1, 1, bs, bs), lambda r, q: (r, q, 0, 0))
-    line = pl.BlockSpec((1, 1, bs), lambda r, q: (r, q, 0))
+    line = pl.BlockSpec((1, 1, 1, bs), lambda r, q: (r, q, 0, 0))
     kspec = pl.BlockSpec((1, 1) + kh.shape, lambda r, q: (0, 0, 0, 0))
 
     out0, out1 = pl.pallas_call(
@@ -196,9 +184,8 @@ def update_color_pallas_lines(quads_blocked, bits, kh, beta: float, color: int,
                   line, line, line, line],
         out_specs=[tile, tile],
         out_shape=[jax.ShapeDtypeStruct((mr, mc, bs, bs), dtype)] * 2,
-        interpret=interpret,
-    )(s0, s1, p0, p1, kh.reshape(1, 1, *kh.shape), bits[0], bits[1],
-      row0, col0, row1, col1)
+        interpret=interpret_mode(),
+    )(s0, s1, p0, p1, kh.reshape(1, 1, *kh.shape), bits[0], bits[1], *lines)
 
     if color == 0:
         return jnp.stack([out0, b, c, out1])
@@ -206,7 +193,6 @@ def update_color_pallas_lines(quads_blocked, bits, kh, beta: float, color: int,
 
 
 def update_color_pallas(quads_blocked, bits, kh, beta: float, color: int,
-                        interpret: bool = True,
                         rule: str = "metropolis_lut"):
     """One colour update of blocked compact quads.
 
@@ -249,7 +235,7 @@ def update_color_pallas(quads_blocked, bits, kh, beta: float, color: int,
         in_specs=specs,
         out_specs=[center, center],
         out_shape=[jax.ShapeDtypeStruct((mr, mc, bs, bs), dtype)] * 2,
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(s0, s1, pas0, pas0, pas0, pas1, pas1, pas1,
       kh.reshape(1, 1, *kh.shape), bits[0], bits[1])
 

@@ -3,7 +3,7 @@
 ``sweep(quads, key, beta)`` runs one full lattice sweep (black + white) with
 counter-based RNG, dispatching to one of three backends:
 
-* ``pallas`` — the fused Pallas kernel (interpret=True on CPU, compiled on TPU)
+* ``pallas`` — the fused Pallas kernel (Mosaic on TPU, interpreted elsewhere)
 * ``ref``    — the pure-jnp oracle with identical bit-level semantics
 * ``xla``    — the paper-faithful Algorithm-2 XLA path (repro.core), its own RNG
 
@@ -37,8 +37,7 @@ def color_bits(key: jax.Array, step, color: int, shape) -> jax.Array:
 
 
 def update_color(quads_blocked: jax.Array, bits: jax.Array, beta: float,
-                 color: int, backend: str = "pallas",
-                 interpret: bool = True, edges=None,
+                 color: int, backend: str = "pallas", edges=None,
                  rule: str = "metropolis_lut") -> jax.Array:
     """backend: 'pallas' (tile-fetch halo), 'pallas_lines' (edge-line halo,
     distribution-capable), or 'ref' (pure-jnp oracle). ``rule`` names a
@@ -47,11 +46,10 @@ def update_color(quads_blocked: jax.Array, bits: jax.Array, beta: float,
     kh = L.kernel_compact(bs, quads_blocked.dtype)
     if backend == "pallas":
         return kern.update_color_pallas(quads_blocked, bits, kh, beta, color,
-                                        interpret=interpret, rule=rule)
+                                        rule=rule)
     if backend == "pallas_lines":
         return kern.update_color_pallas_lines(quads_blocked, bits, kh, beta,
-                                              color, interpret=interpret,
-                                              edges=edges, rule=rule)
+                                              color, edges=edges, rule=rule)
     if backend == "ref":
         return kref.update_color_ref(quads_blocked, bits, kh, beta, color,
                                      rule=rule)
@@ -59,28 +57,24 @@ def update_color(quads_blocked: jax.Array, bits: jax.Array, beta: float,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("beta", "bs", "backend", "interpret",
-                                    "rule"))
+                   static_argnames=("beta", "bs", "backend", "rule"))
 def sweep(quads: jax.Array, key: jax.Array, step, *, beta: float,
           bs: int = L.MXU_BLOCK, backend: str = "pallas",
-          interpret: bool = True,
           rule: str = "metropolis_lut") -> jax.Array:
     """One full sweep of [4, R, C] compact quads. Returns updated quads."""
     qb = _block_quads(quads, bs)
     blk = qb.shape[1:]
     for color in (0, 1):
         bits = color_bits(key, step, color, blk)
-        qb = update_color(qb, bits, beta, color, backend, interpret,
-                          rule=rule)
+        qb = update_color(qb, bits, beta, color, backend, rule=rule)
     return _unblock_quads(qb)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("n_sweeps", "beta", "bs", "backend",
-                                    "interpret", "rule"))
+                                    "rule"))
 def run_sweeps(quads: jax.Array, key: jax.Array, *, n_sweeps: int, beta: float,
                bs: int = L.MXU_BLOCK, backend: str = "pallas",
-               interpret: bool = True,
                rule: str = "metropolis_lut") -> jax.Array:
     """Measurement-free multi-sweep loop on the kernel path."""
     qb = _block_quads(quads, bs)
@@ -89,8 +83,7 @@ def run_sweeps(quads: jax.Array, key: jax.Array, *, n_sweeps: int, beta: float,
     def body(i, q):
         for color in (0, 1):
             bits = color_bits(key, i, color, blk)
-            q = update_color(q, bits, beta, color, backend, interpret,
-                             rule=rule)
+            q = update_color(q, bits, beta, color, backend, rule=rule)
         return q
 
     qb = jax.lax.fori_loop(0, n_sweeps, body, qb)

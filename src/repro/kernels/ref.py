@@ -33,7 +33,7 @@ def update_color_ref(quads_blocked: jax.Array, bits: jax.Array, kh: jax.Array,
     """Oracle with the exact kernel semantics (f32 nn, f32 table, f32
     compare) for any registry rule.
 
-    Same signature as ``update_color_pallas`` minus ``interpret``.
+    Same signature as ``update_color_pallas``.
     """
     a, b, c, d = (quads_blocked[i] for i in range(4))
     khf = kh

@@ -8,20 +8,20 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: (16, 16) = 256 chips, axes (data, model).
     Multi-pod:  (2, 16, 16) = 512 chips, axes (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple, axes: tuple):
-    """Arbitrary mesh (reduced-device tests use (2,2,2) / (2,4) etc.)."""
-    return compat.make_mesh(shape, axes)
+    """Arbitrary mesh over ``jax.devices()`` with Auto (GSPMD-propagated)
+    axis types (reduced-device tests use (2,2,2) / (2,4) etc.)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> tuple:

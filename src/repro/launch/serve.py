@@ -69,7 +69,9 @@ def main(argv=None) -> int:
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args(argv)
 
+    from repro import compile_cache
     from repro.serve import MCServeEngine
+    compile_cache.enable()
     sizes = tuple(int(s) for s in args.sizes.split(","))
     models = tuple(args.models.split(","))
     reqs = make_workload(args.requests, sizes, models, args.sweeps,

@@ -79,11 +79,13 @@ def main(argv=None):
 
     import jax
     import jax.numpy as jnp
+    from repro import compile_cache
     from repro.api import EngineConfig, IsingEngine
     from repro.checkpoint import ckpt
     from repro.core import observables as obs
     from repro.launch import mesh as mesh_lib
 
+    compile_cache.enable()
     shape = tuple(int(x) for x in args.mesh.split(","))
     axes = ("pod", "data", "model")[3 - len(shape):]
     mesh = mesh_lib.make_mesh(shape, axes)

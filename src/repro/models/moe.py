@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.distributed import sharding as SH
 from repro.distributed.sharding import shard_hint
 from repro.models import layers as nn

@@ -6,10 +6,11 @@ must never leak into smoke tests). Tests that genuinely need a mesh spawn a
 subprocess with ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` via
 :func:`run_in_subprocess`.
 
-Subprocess snippets that need ``shard_map`` must import it from
-``repro.compat`` (NOT ``jax.shard_map``): the shim papers over the
-jax.experimental -> jax move and the ``check_rep`` -> ``check_vma`` rename,
-so snippets run on every jax version the container may pin.
+The suite targets the installed jax (0.9): snippets import ``shard_map``
+and ``AbstractMesh`` from jax itself, and meshes come from
+``repro.launch.mesh.make_mesh``. The tests run on the CPU
+(``JAX_PLATFORMS=cpu``), where the Pallas kernels run in interpret mode;
+``tests/test_tpu_compile.py`` compiles them for a described TPU v5e.
 
 Property-based testing note: ``hypothesis`` is not installed in this
 container, so property-style tests are hand-rolled — randomized inputs drawn

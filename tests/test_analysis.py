@@ -78,7 +78,7 @@ def test_ppermute_wire_bytes(subproc):
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from repro.analysis import hlo as H
-    from repro.compat import shard_map
+    from jax import shard_map
     mesh = jax.make_mesh((4,), ("d",))
 
     def f(x):

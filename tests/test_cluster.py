@@ -405,7 +405,7 @@ def test_tau_collapse_at_tc_128():
 def test_mesh_labels_and_states_bitwise_single(subproc):
     out = subproc("""
     import numpy as np, jax, jax.numpy as jnp
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.distributed import ising as dising
     from repro.core import lattice as L, measure
     from repro.cluster import mesh as cmesh, sweep as csweep, bonds as B
@@ -483,7 +483,7 @@ def test_mesh_1d_row_decomposition_bitwise(subproc):
     """A 4x1 device grid (rows only; column wrap stays local)."""
     out = subproc("""
     import numpy as np, jax, jax.numpy as jnp
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.distributed import ising as dising
     from repro.core import lattice as L
     from repro.cluster import mesh as cmesh, sweep as csweep, bonds as B

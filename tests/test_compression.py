@@ -56,7 +56,7 @@ def test_psum_compressed_across_pod_axis(subproc):
     out = subproc("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.distributed import compression as C
     from repro.launch import mesh as mesh_lib
 

@@ -9,6 +9,8 @@ The engine's contract (module docstring of repro.api.engine):
 * invalid configuration combinations raise `EngineConfigError` with an
   actionable message.
 """
+import textwrap
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -289,3 +291,63 @@ def test_mesh_dispatch_and_replica_sharding(subproc):
     print("MESH_ENGINE_OK")
     """, devices=4)
     assert "MESH_ENGINE_OK" in out
+
+
+_OLD_INIT = {
+    # The eager full-lattice construction that mesh init used to run on the
+    # default device before device_put; the sharded init must equal it.
+    "ising2d": """
+        cfg = EngineConfig(size=64, beta=beta, n_sweeps=1, block_size=8,
+                           topology="mesh", mesh_shape=(2, 2), hot=hot)
+        full = (L.random_lattice(key, 64, 64, jnp.bfloat16) if hot
+                else L.cold_lattice(64, 64, jnp.bfloat16))
+        q = L.to_quads(full)
+        want = jnp.stack([L.block(q[i], 8) for i in range(4)])
+    """,
+    "ising3d": """
+        cfg = EngineConfig(size=8, beta=beta, n_sweeps=1, dims=3,
+                           topology="mesh", mesh_shape=(2, 2), hot=hot)
+        want = (I3.random_lattice3d(key, 8, 8, 8, jnp.bfloat16) if hot
+                else I3.cold_lattice3d(8, 8, 8, jnp.bfloat16))
+    """,
+    "potts_cb": """
+        cfg = EngineConfig(size=16, beta=beta, n_sweeps=1, model="potts",
+                           q=3, topology="mesh", mesh_shape=(2, 2), hot=hot)
+        want = (potts_state.random_state(key, 16, 16, 3) if hot
+                else potts_state.cold_state(16, 16))
+    """,
+    "potts_cluster": """
+        cfg = EngineConfig(size=32, beta=beta, n_sweeps=1, model="potts",
+                           q=3, algorithm="swendsen_wang", block_size=8,
+                           topology="mesh", mesh_shape=(2, 2), hot=hot)
+        full = (potts_state.random_state(key, 32, 32, 3) if hot
+                else potts_state.cold_state(32, 32))
+        q = L.to_quads(full)
+        want = jnp.stack([L.block(q[i], 8) for i in range(4)])
+    """,
+}
+
+
+@pytest.mark.parametrize("family", sorted(_OLD_INIT))
+def test_sharded_init_matches_full_lattice_construction(subproc, family):
+    """Mesh init builds the state already sharded (never the global lattice
+    on one device) and stays bitwise equal to the old eager full-lattice
+    construction, hot and cold."""
+    body = textwrap.indent(textwrap.dedent(_OLD_INIT[family]), " " * 8)
+    out = subproc(f"""
+    import numpy as np, jax, jax.numpy as jnp
+    from repro.api import IsingEngine, EngineConfig
+    from repro.core import ising3d as I3
+    from repro.core import lattice as L
+    from repro.potts import state as potts_state
+    key = jax.random.PRNGKey(3)
+    for hot, beta in ((True, 0.3), (False, 0.6)):
+{body}
+        eng = IsingEngine(cfg)
+        got = eng.init(key)
+        assert got.sharding == eng.state_sharding(), got.sharding
+        assert len(got.sharding.device_set) == 4
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    print("SHARDED_INIT_OK")
+    """, devices=4)
+    assert "SHARDED_INIT_OK" in out

@@ -13,12 +13,12 @@ import pytest
 
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import abstract_mesh
+from jax.sharding import AbstractMesh
 from repro.distributed import halo
 
 
 def test_from_mesh_static_properties():
-    mesh = abstract_mesh((2, 4, 2), ("pod", "data", "model"))
+    mesh = AbstractMesh((2, 4, 2), ("pod", "data", "model"))
     spec = halo.HaloSpec.from_mesh(mesh, (("pod", "data"), "model", None))
     assert spec.ndim == 3
     assert spec.shard_counts() == (8, 2, 1)
@@ -29,7 +29,7 @@ def test_from_mesh_static_properties():
 
 
 def test_partition_spec_layouts():
-    mesh = abstract_mesh((2, 2), ("data", "model"))
+    mesh = AbstractMesh((2, 2), ("data", "model"))
     spec = halo.HaloSpec.from_mesh(mesh, ("data", "model"))
     assert spec.partition_spec() == P(("data",), ("model",))
     assert spec.partition_spec(leading=1, trailing=2) == \
@@ -47,7 +47,8 @@ def test_spec2d_matches_legacy_vocabulary():
 
 def test_neighbor_and_index_unsharded_is_local_roll():
     """On a 1x1 mesh every primitive must degrade to plain torus ops."""
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1, 1), ("data", "model"))
     spec = halo.HaloSpec.from_mesh(mesh, ("data", "model"))
     x = jnp.arange(48, dtype=jnp.float32).reshape(6, 8)
@@ -87,7 +88,7 @@ def test_neighbor_round_trips_under_shard_map(subproc, mesh_shape, axes,
     directions, on 1-, 2-, and 3-axis shard grids (2-D and 3-D arrays)."""
     out = subproc(f"""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.distributed import halo
     from repro.launch import mesh as mesh_lib
 
@@ -124,7 +125,7 @@ def test_blocked_quad_edges_match_gathered_default(subproc):
     ``default_edges`` of the gathered lattice — for all four sides."""
     out = subproc("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core import checkerboard as cb, lattice as L
     from repro.distributed import halo
     from repro.launch import mesh as mesh_lib

@@ -476,7 +476,7 @@ def test_q3_heat_bath_metropolis_sw_equilibrium_agree():
 def test_potts_mesh_bitwise_single(subproc):
     out = subproc("""
     import numpy as np, jax, jax.numpy as jnp
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.distributed import ising as dising
     from repro.core import lattice as L, measure
     from repro.potts import mesh as pmesh, sweep as psweep
@@ -534,7 +534,7 @@ def test_potts_mesh_engine_and_1d(subproc):
     out = subproc("""
     import numpy as np, jax, jax.numpy as jnp
     from repro.api import EngineConfig, IsingEngine
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.distributed import ising as dising
     from repro.core import lattice as L
     from repro.potts import mesh as pmesh, sweep as psweep
@@ -591,7 +591,7 @@ def test_potts_cb_mesh_bitwise_single(subproc):
     out = subproc("""
     import numpy as np, jax, jax.numpy as jnp
     from jax.sharding import NamedSharding
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core import measure
     from repro.distributed import ising as dising
     from repro.potts import mesh as pmesh, rules as PR, state as PS

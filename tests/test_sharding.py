@@ -6,12 +6,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import abstract_mesh
+from jax.sharding import AbstractMesh
 from repro.distributed import sharding as SH
 
 
 def _mesh(shape=(2, 16, 16), axes=("pod", "data", "model")):
-    return abstract_mesh(shape, axes)
+    return AbstractMesh(shape, axes)
 
 
 def test_basic_resolution():
