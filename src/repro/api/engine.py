@@ -75,6 +75,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import checkerboard as cb
 from repro.core import ising3d as I3
@@ -805,11 +806,13 @@ class IsingEngine:
 
             def body(carry, step):
                 qb = carry
-                for color in (0, 1):
-                    bits = kops.color_bits(key, step, color, qb.shape[1:])
-                    qb = kops.update_color(qb, bits, c.beta, color,
-                                           backend=c.backend, rule=rule)
-                return qb, measure.blocked_stats(qb)
+                with jax.named_scope(L.SWEEP):
+                    for color in (0, 1):
+                        bits = kops.color_bits(key, step, color,
+                                               qb.shape[1:])
+                        qb = kops.update_color(qb, bits, c.beta, color,
+                                               backend=c.backend, rule=rule)
+                    return qb, measure.blocked_stats(qb)
 
             qb0 = kops._block_quads(state, bs)
             qb, (ms, es) = jax.lax.scan(body, qb0, jnp.arange(c.n_sweeps))
@@ -1038,26 +1041,50 @@ class IsingEngine:
         Single-chain XLA runs are bitwise-identical to
         :func:`repro.core.sampler.run_chain`; ensemble replica i is
         bitwise-identical to a single run keyed ``fold_in(key, i)``.
+
+        Under the profiler the call is the host span ``engine.run``
+        (arguments ``scenario``, ``sweeps`` and ``sites``, the lattice sites
+        one sweep updates). On the chain and mesh paths it nests
+        ``engine.launch`` (the chunk program's call returns),
+        ``engine.wait`` (its streamed outputs are ready) and
+        ``engine.moments`` (the host folds them into ``moments``).
         """
         c = self.cfg
         scen = self._scenario()
+        sites = c.size ** 3 if c.dims == 3 else c.size * c.resolved_width()
+        with TraceAnnotation("engine.run", scenario=scen, sweeps=c.n_sweeps,
+                             sites=sites * max(c.n_replicas(), 1)):
+            return self._run(scen, state, key)
+
+    def _run(self, scen: str, state: jax.Array,
+             key: jax.Array) -> EngineResult:
+        c = self.cfg
         if scen == "tempering":
             return self._run_tempering(state, key)
         if scen == "chain":
-            if c.measure:
+            if not c.measure:
+                with TraceAnnotation("engine.launch"):
+                    return EngineResult(sampler.run_sweeps(
+                        state, key, self._chain_cfg()))
+            with TraceAnnotation("engine.launch"):
                 final, ms, es = sampler.run_chain(state, key,
                                                   self._chain_cfg())
-                return EngineResult(final, ms, es,
-                                    self._series_moments(ms, es))
-            return EngineResult(sampler.run_sweeps(state, key,
-                                                   self._chain_cfg()))
-        if scen in ("mesh", "mesh3d", "potts_cb_mesh", "cluster_mesh",
-                    "potts_cluster_mesh"):
+            with TraceAnnotation("engine.wait"):
+                ms, es = jax.block_until_ready((ms, es))
+            with TraceAnnotation("engine.moments"):
+                mom = self._series_moments(ms, es)
+            return EngineResult(final, ms, es, mom)
+        if scen in self._MESH_SCENARIOS:
             runner = self._mesh_runner_for(scen)
-            if c.measure:
+            if not c.measure:
+                with TraceAnnotation("engine.launch"):
+                    return EngineResult(runner(c.n_sweeps)(state, key))
+            with TraceAnnotation("engine.launch"):
                 final, mom = runner(c.n_sweeps, measured=True)(state, key)
+            with TraceAnnotation("engine.wait"):
+                mom = jax.block_until_ready(mom)
+            with TraceAnnotation("engine.moments"):
                 return EngineResult(final, moments=measure.finalize(mom))
-            return EngineResult(runner(c.n_sweeps)(state, key))
         runner_key = scen
         if runner_key not in self._runner_cache:
             self._runner_cache[runner_key] = {
@@ -1077,6 +1104,23 @@ class IsingEngine:
                  if c.betas and scen in ("ensemble", "cluster", "potts_cb",
                                          "potts_cluster") else {})
         return EngineResult(final, ms, es, mom, extra)
+
+    def lower(self, state, key) -> jax.stages.Lowered:
+        """The chunk program :meth:`run` launches, lowered for ``state`` and
+        ``key`` (arrays or ``jax.ShapeDtypeStruct``) and not run: its HLO
+        carries the sweep's named scopes (``lattice.SCOPES``) as op names.
+        Chain and mesh scenarios."""
+        c = self.cfg
+        scen = self._scenario()
+        if scen == "chain":
+            impl = (sampler._run_chain_impl if c.measure
+                    else sampler._run_sweeps_impl)
+            return impl.lower(state, key, self._chain_cfg())
+        if scen == "mesh":
+            return self._mesh_runner(c.n_sweeps, measured=c.measure).lower(
+                state, key)
+        _config_error(f"lower() covers the chain and mesh scenarios, not "
+                      f"{scen!r}")
 
     def _series_moments(self, ms, es) -> Optional[dict]:
         """Moments from an already-streamed per-sweep series (scan paths) —

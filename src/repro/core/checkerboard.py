@@ -42,7 +42,9 @@ def _flip(sigma: jax.Array, nn: jax.Array, probs: jax.Array, beta,
     ``accept`` is a rule name or alias: 'lut' / 'exp' (Metropolis, bitwise
     identical to the pre-registry implementations) or 'heat_bath'.
     """
-    return rules.get_rule(accept).flip_probs(sigma, nn, probs, beta, field)
+    with jax.named_scope(L.FLIP):
+        return rules.get_rule(accept).flip_probs(sigma, nn, probs, beta,
+                                                 field)
 
 
 # ---------------------------------------------------------------------------
@@ -171,26 +173,32 @@ def edge_lines(a, b, c, d, color: int, edges=default_edges):
 def nn_black(a, b, c, d, kh, edges=default_edges):
     """nn sums for the black quads (A, D); inputs are [mr, mc, bs, bs]."""
     kht = kh.T
-    row0, col0, row1, col1 = edge_lines(a, b, c, d, 0, edges)
+    with jax.named_scope(L.HALO):
+        row0, col0, row1, col1 = edge_lines(a, b, c, d, 0, edges)
     nn_a = _bmm(b, kh) + _bmm_t(kht, c)
-    nn_a = nn_a.at[:, :, :, 0].add(col0)    # west col of B
-    nn_a = nn_a.at[:, :, 0, :].add(row0)    # north row of C
+    with jax.named_scope(L.HALO):
+        nn_a = nn_a.at[:, :, :, 0].add(col0)    # west col of B
+        nn_a = nn_a.at[:, :, 0, :].add(row0)    # north row of C
     nn_d = _bmm_t(kh, b) + _bmm(c, kht)
-    nn_d = nn_d.at[:, :, -1, :].add(row1)   # south row of B
-    nn_d = nn_d.at[:, :, :, -1].add(col1)   # east col of C
+    with jax.named_scope(L.HALO):
+        nn_d = nn_d.at[:, :, -1, :].add(row1)   # south row of B
+        nn_d = nn_d.at[:, :, :, -1].add(col1)   # east col of C
     return nn_a, nn_d
 
 
 def nn_white(a, b, c, d, kh, edges=default_edges):
     """nn sums for the white quads (B, C)."""
     kht = kh.T
-    row0, col0, row1, col1 = edge_lines(a, b, c, d, 1, edges)
+    with jax.named_scope(L.HALO):
+        row0, col0, row1, col1 = edge_lines(a, b, c, d, 1, edges)
     nn_b = _bmm(a, kht) + _bmm_t(kht, d)
-    nn_b = nn_b.at[:, :, :, -1].add(col0)   # east col of A
-    nn_b = nn_b.at[:, :, 0, :].add(row0)    # north row of D
+    with jax.named_scope(L.HALO):
+        nn_b = nn_b.at[:, :, :, -1].add(col0)   # east col of A
+        nn_b = nn_b.at[:, :, 0, :].add(row0)    # north row of D
     nn_c = _bmm_t(kh, a) + _bmm(d, kh)
-    nn_c = nn_c.at[:, :, -1, :].add(row1)   # south row of A
-    nn_c = nn_c.at[:, :, :, 0].add(col1)    # west col of D
+    with jax.named_scope(L.HALO):
+        nn_c = nn_c.at[:, :, -1, :].add(row1)   # south row of A
+        nn_c = nn_c.at[:, :, :, 0].add(col1)    # west col of D
     return nn_b, nn_c
 
 
@@ -210,7 +218,8 @@ def update_color_compact(quads: jax.Array, probs0: jax.Array,
         turns into the bond energy without recomputing neighbour sums.
     """
     kh = L.kernel_compact(block_size, quads.dtype)
-    a, b, c, d = (L.block(quads[i], block_size) for i in range(4))
+    with jax.named_scope(L.LAYOUT):
+        a, b, c, d = (L.block(quads[i], block_size) for i in range(4))
     if color == 0:  # black: flip A and D
         nn0, nn1 = nn_black(a, b, c, d, kh, edges)
         s0, s1 = a, d
@@ -221,12 +230,13 @@ def update_color_compact(quads: jax.Array, probs0: jax.Array,
     p1 = L.block(probs1, block_size)
     new0 = _flip(s0, nn0.astype(s0.dtype), p0, beta, accept, field)
     new1 = _flip(s1, nn1.astype(s1.dtype), p1, beta, accept, field)
-    if color == 0:
-        out = jnp.stack([L.unblock(new0), quads[1], quads[2],
-                         L.unblock(new1)])
-    else:
-        out = jnp.stack([quads[0], L.unblock(new0), L.unblock(new1),
-                         quads[3]])
+    with jax.named_scope(L.LAYOUT):
+        if color == 0:
+            out = jnp.stack([L.unblock(new0), quads[1], quads[2],
+                             L.unblock(new1)])
+        else:
+            out = jnp.stack([quads[0], L.unblock(new0), L.unblock(new1),
+                             quads[3]])
     if return_stats:
         return out, (new0, new1, nn0, nn1)
     return out
@@ -238,9 +248,13 @@ def sweep_compact(quads: jax.Array, probs: jax.Array, beta,
                   field: float = 0.0) -> jax.Array:
     """One full sweep (black then white). probs: [4, R, C] uniforms, laid out
     as [black0, black1, white0, white1]."""
-    quads = update_color_compact(quads, probs[0], probs[1], beta, 0,
+    with jax.named_scope(L.LAYOUT):
+        p0, p1 = probs[0], probs[1]
+    quads = update_color_compact(quads, p0, p1, beta, 0,
                                  block_size, accept, edges, field)
-    return update_color_compact(quads, probs[2], probs[3], beta, 1,
+    with jax.named_scope(L.LAYOUT):
+        p2, p3 = probs[2], probs[3]
+    return update_color_compact(quads, p2, p3, beta, 1,
                                 block_size, accept, edges, field)
 
 

@@ -25,6 +25,15 @@ WHITE_QUADS = (Q01, Q10)
 
 MXU_BLOCK = 128
 
+# Device scopes (``jax.named_scope``) naming the sweep's work in a profile.
+# A compiled op carries the scope path in its ``op_name`` metadata and is
+# charged to the innermost of these names: the parent sweep, the random
+# draw, moves between layouts, halo lines, acceptance, and the streamed
+# (m, E). The benchmark's trace reader (chipbench/scopes.py) keeps its own
+# copy of the tuple.
+SCOPES = ("sweep", "rng", "layout", "halo", "flip", "measure")
+SWEEP, RNG, LAYOUT, HALO, FLIP, MEASURE = SCOPES
+
 
 def random_lattice(key: jax.Array, height: int, width: int,
                    dtype=jnp.bfloat16) -> jax.Array:
@@ -67,13 +76,15 @@ def block(x: jax.Array, bs: int = MXU_BLOCK) -> jax.Array:
     r, c = x.shape
     if r % bs or c % bs:
         raise ValueError(f"{x.shape} not divisible by block {bs}")
-    return x.reshape(r // bs, bs, c // bs, bs).transpose(0, 2, 1, 3)
+    with jax.named_scope(LAYOUT):
+        return x.reshape(r // bs, bs, c // bs, bs).transpose(0, 2, 1, 3)
 
 
 def unblock(xb: jax.Array) -> jax.Array:
     """[mr, mc, bs, bs] -> [mr*bs, mc*bs]; inverse of :func:`block`."""
     mr, mc, bs, _ = xb.shape
-    return xb.transpose(0, 2, 1, 3).reshape(mr * bs, mc * bs)
+    with jax.named_scope(LAYOUT):
+        return xb.transpose(0, 2, 1, 3).reshape(mr * bs, mc * bs)
 
 
 def kernel_naive(n: int, dtype=jnp.bfloat16) -> jax.Array:

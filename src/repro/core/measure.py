@@ -60,11 +60,12 @@ def _psum(x, axis_names):
 def magnetization_mean(quads, n_spins: int, axis_names=()) -> jax.Array:
     """Global mean spin from any local spin tensor (quads, blocked quads, a
     tuple of quad arrays, ...). ``n_spins`` is the GLOBAL spin count."""
-    if isinstance(quads, (tuple, list)):
-        s = sum(jnp.sum(q.astype(jnp.float32)) for q in quads)
-    else:
-        s = jnp.sum(quads.astype(jnp.float32))
-    return _psum(s, axis_names) / jnp.float32(n_spins)
+    with jax.named_scope(L.MEASURE):
+        if isinstance(quads, (tuple, list)):
+            s = sum(jnp.sum(q.astype(jnp.float32)) for q in quads)
+        else:
+            s = jnp.sum(quads.astype(jnp.float32))
+        return _psum(s, axis_names) / jnp.float32(n_spins)
 
 
 def bond_energy_from_nn(s0: jax.Array, s1: jax.Array, nn0: jax.Array,
@@ -77,9 +78,11 @@ def bond_energy_from_nn(s0: jax.Array, s1: jax.Array, nn0: jax.Array,
     since they only read the other colour). Each bond counted once:
     E/N = -(sum sigma*nn over one colour) / N.
     """
-    local = (jnp.sum(s0.astype(jnp.float32) * nn0.astype(jnp.float32))
-             + jnp.sum(s1.astype(jnp.float32) * nn1.astype(jnp.float32)))
-    return -_psum(local, axis_names) / jnp.float32(n_spins)
+    with jax.named_scope(L.MEASURE):
+        local = (jnp.sum(s0.astype(jnp.float32) * nn0.astype(jnp.float32))
+                 + jnp.sum(s1.astype(jnp.float32)
+                           * nn1.astype(jnp.float32)))
+        return -_psum(local, axis_names) / jnp.float32(n_spins)
 
 
 def blocked_stats(qb, n_spins: Optional[int] = None, kh=None,
@@ -99,7 +102,8 @@ def blocked_stats(qb, n_spins: Optional[int] = None, kh=None,
         edges = cb.default_edges
     if n_spins is None:
         n_spins = 4 * a.size
-    nn_b, nn_c = cb.nn_white(a, b, c, d, kh, edges)
+    with jax.named_scope(L.MEASURE):
+        nn_b, nn_c = cb.nn_white(a, b, c, d, kh, edges)
     m = magnetization_mean((a, b, c, d), n_spins, axis_names)
     e = bond_energy_from_nn(b, c, nn_b, nn_c, n_spins, axis_names)
     return m, e
@@ -113,10 +117,14 @@ def sweep_compact_measured(quads: jax.Array, probs: jax.Array, beta,
     twin of :func:`repro.core.checkerboard.sweep_compact`, bitwise-identical
     state evolution, zero extra matmuls for the energy (it reuses the white
     half-update's nn tensors)."""
-    quads = cb.update_color_compact(quads, probs[0], probs[1], beta, 0,
+    with jax.named_scope(L.LAYOUT):
+        p0, p1 = probs[0], probs[1]
+    quads = cb.update_color_compact(quads, p0, p1, beta, 0,
                                     block_size, accept, edges, field)
+    with jax.named_scope(L.LAYOUT):
+        p2, p3 = probs[2], probs[3]
     quads, (new0, new1, nn0, nn1) = cb.update_color_compact(
-        quads, probs[2], probs[3], beta, 1, block_size, accept, edges,
+        quads, p2, p3, beta, 1, block_size, accept, edges,
         field, return_stats=True)
     n_spins = quads.size
     m = magnetization_mean(quads, n_spins)

@@ -40,17 +40,19 @@ class ChainConfig:
 
 def sweep_probs(key: jax.Array, step, shape, dtype) -> jax.Array:
     """Uniforms for one sweep: [4, R, C] (black A, black D, white B, white C)."""
-    k = jax.random.fold_in(key, step)
-    return jax.random.uniform(k, (4,) + shape, dtype)
+    with jax.named_scope(L.RNG):
+        k = jax.random.fold_in(key, step)
+        return jax.random.uniform(k, (4,) + shape, dtype)
 
 
 def make_sweep_fn(cfg: ChainConfig):
     dtype = jnp.dtype(cfg.prob_dtype)
 
     def one_sweep(quads: jax.Array, key: jax.Array, step) -> jax.Array:
-        probs = sweep_probs(key, step, quads.shape[1:], dtype)
-        return cb.sweep_compact(quads, probs, cfg.beta, cfg.block_size,
-                                cfg.accept, field=cfg.field)
+        with jax.named_scope(L.SWEEP):
+            probs = sweep_probs(key, step, quads.shape[1:], dtype)
+            return cb.sweep_compact(quads, probs, cfg.beta, cfg.block_size,
+                                    cfg.accept, field=cfg.field)
 
     return one_sweep
 
@@ -63,11 +65,11 @@ def _run_chain_impl(quads, key, cfg: ChainConfig):
     pdt = jnp.dtype(cfg.prob_dtype)
 
     def body(carry, step):
-        probs = sweep_probs(key, step, carry.shape[1:], pdt)
-        q, (m, e) = ms.sweep_compact_measured(carry, probs, cfg.beta,
-                                              cfg.block_size, cfg.accept,
-                                              field=cfg.field)
-        return q, (m, e)
+        with jax.named_scope(L.SWEEP):
+            probs = sweep_probs(key, step, carry.shape[1:], pdt)
+            return ms.sweep_compact_measured(carry, probs, cfg.beta,
+                                             cfg.block_size, cfg.accept,
+                                             field=cfg.field)
 
     final, (m_t, e_t) = jax.lax.scan(body, quads, jnp.arange(cfg.n_sweeps))
     return final, m_t, e_t

@@ -57,6 +57,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core import lattice as L
+
 _INV_2_24 = 1.0 / float(1 << 24)
 
 # x = sigma * nn (metropolis) or nn (heat-bath) lattice values, 2-D torus.
@@ -228,10 +230,18 @@ def metropolis_acceptance(nn: jax.Array, sigma: jax.Array, beta,
 # ---------------------------------------------------------------------------
 
 
+def _as_uniforms(probs: jax.Array, dtype) -> jax.Array:
+    """The uniforms cast to the acceptance dtype, under the ``rng`` scope:
+    XLA fuses the draw into this cast, which ends the fusion, so the draw's
+    time is charged to ``rng`` and not to ``flip``."""
+    with jax.named_scope(L.RNG):
+        return probs.astype(dtype)
+
+
 def _metropolis_flip_probs(method):
     def flip(sigma, nn, probs, beta, field: float = 0.0):
         acc = metropolis_acceptance(nn, sigma, beta, method, field)
-        flips = (probs.astype(acc.dtype) < acc)
+        flips = (_as_uniforms(probs, acc.dtype) < acc)
         # sigma - 2*flips*sigma, but branch-free select keeps spins exact.
         return jnp.where(flips, -sigma, sigma)
     return flip
@@ -280,7 +290,7 @@ def _heat_bath_flip_probs(sigma, nn, probs, beta, field: float = 0.0):
         arg = arg + jnp.float32(field)
     p_up = jax.nn.sigmoid(2.0 * jnp.asarray(beta, jnp.float32) * arg)
     p_up = p_up.astype(sigma.dtype)
-    up = probs.astype(p_up.dtype) < p_up
+    up = _as_uniforms(probs, p_up.dtype) < p_up
     return jnp.where(up, jnp.ones_like(sigma), -jnp.ones_like(sigma))
 
 

@@ -36,6 +36,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from jax import shard_map
+from repro.core import lattice as L
 from repro.core import measure
 
 
@@ -55,10 +56,12 @@ class MeshModel:
     pack: Optional[Callable] = None             # carry -> local state
 
     def _unpack(self, st):
-        return self.unpack(st) if self.unpack is not None else st
+        with jax.named_scope(L.LAYOUT):
+            return self.unpack(st) if self.unpack is not None else st
 
     def _pack(self, carry):
-        return self.pack(carry) if self.pack is not None else carry
+        with jax.named_scope(L.LAYOUT):
+            return self.pack(carry) if self.pack is not None else carry
 
     def _sweep_measured(self):
         if self.sweep_measured is not None:

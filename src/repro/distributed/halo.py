@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import checkerboard as cb
+from repro.core import lattice as L
 
 
 def _perm(n: int, delta: int):
@@ -173,7 +174,9 @@ class HaloSpec:
         ax = self.axes[dim]
         if ax.n_shards == 1:
             return plane
-        return lax.ppermute(plane, ax.mesh_axes, _perm(ax.n_shards, delta))
+        with jax.named_scope(L.HALO):
+            return lax.ppermute(plane, ax.mesh_axes,
+                                _perm(ax.n_shards, delta))
 
     def plane(self, x: jax.Array, dim: int, delta: int) -> jax.Array:
         """The boundary plane this device's ``delta``-neighbour along
@@ -187,12 +190,13 @@ class HaloSpec:
         global torus: a local roll with the wrap plane overwritten by the
         adjacent device's boundary plane (one ppermute per sharded edge)."""
         ax = self.axes[dim]
-        out = jnp.roll(x, -delta, dim)
-        if ax.n_shards > 1:
-            dst = -1 if delta > 0 else 0
-            out = out.at[_slc(x.ndim, dim, dst)].set(
-                self.plane(x, dim, delta))
-        return out
+        with jax.named_scope(L.HALO):
+            out = jnp.roll(x, -delta, dim)
+            if ax.n_shards > 1:
+                dst = -1 if delta > 0 else 0
+                out = out.at[_slc(x.ndim, dim, dst)].set(
+                    self.plane(x, dim, delta))
+            return out
 
 
 # ---------------------------------------------------------------------------

@@ -68,9 +68,10 @@ def lattice_sharding(mesh, cfg: DistIsingConfig) -> NamedSharding:
 
 
 def _device_key(key: jax.Array, cfg: DistIsingConfig, ncols: int) -> jax.Array:
-    row = jax.lax.axis_index(cfg.row_axes)
-    col = jax.lax.axis_index(cfg.col_axes)
-    return jax.random.fold_in(key, row * ncols + col)
+    with jax.named_scope(L.RNG):
+        row = jax.lax.axis_index(cfg.row_axes)
+        col = jax.lax.axis_index(cfg.col_axes)
+        return jax.random.fold_in(key, row * ncols + col)
 
 
 def _draw_bits(k: jax.Array, shape, cfg: DistIsingConfig) -> jax.Array:
@@ -114,11 +115,13 @@ def _local_color_update(quads, key, step, color, cfg, edges,
     update already computed (XLA backend only — the Pallas kernel keeps nn
     in VMEM; callers fall back to ``measure.blocked_stats`` there).
     """
-    k = jax.random.fold_in(jax.random.fold_in(key, step), color)
+    with jax.named_scope(L.RNG):
+        k = jax.random.fold_in(jax.random.fold_in(key, step), color)
     a, b, c, d = quads
     blk = a.shape
     if cfg.backend == "pallas_lines":
-        bits = jax.random.bits(k, (2,) + blk, jnp.uint32)
+        with jax.named_scope(L.RNG):
+            bits = jax.random.bits(k, (2,) + blk, jnp.uint32)
         out = kops.update_color(jnp.stack(quads), bits, cfg.beta, color,
                                 backend="pallas_lines", edges=edges,
                                 rule=cfg.bits_rule())
@@ -133,11 +136,17 @@ def _local_color_update(quads, key, step, color, cfg, edges,
         s0, s1 = b, c
     if cfg.pipeline == "opt":
         rule = update_rules.get_rule(cfg.bits_rule())
-        bits = _draw_bits(k, (2,) + blk, cfg)
-        new0 = rule.flip_bits_int(s0, nn0.astype(s0.dtype), bits[0], cfg.beta)
-        new1 = rule.flip_bits_int(s1, nn1.astype(s1.dtype), bits[1], cfg.beta)
+        with jax.named_scope(L.RNG):
+            bits = _draw_bits(k, (2,) + blk, cfg)
+        with jax.named_scope(L.FLIP):
+            new0 = rule.flip_bits_int(s0, nn0.astype(s0.dtype), bits[0],
+                                      cfg.beta)
+            new1 = rule.flip_bits_int(s1, nn1.astype(s1.dtype), bits[1],
+                                      cfg.beta)
     else:  # paper-faithful float pipeline
-        probs = jax.random.uniform(k, (2,) + blk, jnp.dtype(cfg.prob_dtype))
+        with jax.named_scope(L.RNG):
+            probs = jax.random.uniform(k, (2,) + blk,
+                                       jnp.dtype(cfg.prob_dtype))
         new0 = cb._flip(s0, nn0.astype(s0.dtype), probs[0], cfg.beta,
                         cfg.probs_rule())
         new1 = cb._flip(s1, nn1.astype(s1.dtype), probs[1], cfg.beta,
@@ -209,11 +218,12 @@ def mesh_model(mesh, cfg: DistIsingConfig) -> decomp.MeshModel:
     n_dev = spec.n_devices()
 
     def sweep(quads, key, step):
-        dkey = _device_key(key, cfg, ncols)
-        for color in (0, 1):
-            quads = _local_color_update(quads, dkey, step, color, cfg,
-                                        edges)
-        return quads
+        with jax.named_scope(L.SWEEP):
+            dkey = _device_key(key, cfg, ncols)
+            for color in (0, 1):
+                quads = _local_color_update(quads, dkey, step, color, cfg,
+                                            edges)
+            return quads
 
     def stats(quads):
         n_spins = 4 * quads[0].size * n_dev
@@ -221,20 +231,21 @@ def mesh_model(mesh, cfg: DistIsingConfig) -> decomp.MeshModel:
                                      axis_names=axes)
 
     def sweep_measured(quads, key, step):
-        dkey = _device_key(key, cfg, ncols)
-        n_spins = 4 * quads[0].size * n_dev
-        quads = _local_color_update(quads, dkey, step, 0, cfg, edges)
-        quads, st = _local_color_update(quads, dkey, step, 1, cfg,
-                                        edges, return_stats=True)
-        if st is not None:
-            new0, new1, nn0, nn1 = st
-            m = measure.magnetization_mean(quads, n_spins, axes)
-            e = measure.bond_energy_from_nn(new0, new1, nn0, nn1,
-                                            n_spins, axes)
-        else:  # pallas_lines: nn stays in VMEM; one stencil recompute
-            m, e = measure.blocked_stats(quads, n_spins, edges=edges,
-                                         axis_names=axes)
-        return quads, (m, e)
+        with jax.named_scope(L.SWEEP):
+            dkey = _device_key(key, cfg, ncols)
+            n_spins = 4 * quads[0].size * n_dev
+            quads = _local_color_update(quads, dkey, step, 0, cfg, edges)
+            quads, st = _local_color_update(quads, dkey, step, 1, cfg,
+                                            edges, return_stats=True)
+            if st is not None:
+                new0, new1, nn0, nn1 = st
+                m = measure.magnetization_mean(quads, n_spins, axes)
+                e = measure.bond_energy_from_nn(new0, new1, nn0, nn1,
+                                                n_spins, axes)
+            else:  # pallas_lines: nn stays in VMEM; one stencil recompute
+                m, e = measure.blocked_stats(quads, n_spins, edges=edges,
+                                             axis_names=axes)
+            return quads, (m, e)
 
     return decomp.MeshModel(
         state_spec=lattice_spec(cfg), sweep=sweep, stats=stats,

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core import lattice as L
 from repro.core import update_rules
 
 
@@ -167,8 +168,9 @@ def update_color_pallas_lines(quads_blocked, bits, kh, beta: float, color: int,
     _, mr, mc, bs, _ = quads_blocked.shape
     dtype = quads_blocked.dtype
 
-    lines = [x.reshape(mr, mc, 1, bs)
-             for x in cb.edge_lines(a, b, c, d, color, edges)]
+    with jax.named_scope(L.HALO):
+        lines = [x.reshape(mr, mc, 1, bs)
+                 for x in cb.edge_lines(a, b, c, d, color, edges)]
     s0, s1 = (a, d) if color == 0 else (b, c)
     p0, p1 = (b, c) if color == 0 else (a, d)
 
@@ -179,6 +181,7 @@ def update_color_pallas_lines(quads_blocked, bits, kh, beta: float, color: int,
     out0, out1 = pl.pallas_call(
         functools.partial(_update_kernel_lines, color=color,
                           beta=float(beta), rule=rule),
+        name="checkerboard_update_lines",
         grid=(mr, mc),
         in_specs=[tile, tile, tile, tile, kspec, tile, tile,
                   line, line, line, line],
@@ -231,6 +234,7 @@ def update_color_pallas(quads_blocked, bits, kh, beta: float, color: int,
     out0, out1 = pl.pallas_call(
         functools.partial(_update_kernel, color=color, beta=float(beta),
                           rule=rule),
+        name="checkerboard_update",
         grid=(mr, mc),
         in_specs=specs,
         out_specs=[center, center],

@@ -23,17 +23,20 @@ from repro.kernels import ref as kref
 
 
 def _block_quads(quads: jax.Array, bs: int) -> jax.Array:
-    return jnp.stack([L.block(quads[i], bs) for i in range(4)])
+    with jax.named_scope(L.LAYOUT):
+        return jnp.stack([L.block(quads[i], bs) for i in range(4)])
 
 
 def _unblock_quads(qb: jax.Array) -> jax.Array:
-    return jnp.stack([L.unblock(qb[i]) for i in range(4)])
+    with jax.named_scope(L.LAYOUT):
+        return jnp.stack([L.unblock(qb[i]) for i in range(4)])
 
 
 def color_bits(key: jax.Array, step, color: int, shape) -> jax.Array:
     """uint32 bits for the two active quads of one colour update."""
-    k = jax.random.fold_in(jax.random.fold_in(key, step), color)
-    return jax.random.bits(k, (2,) + tuple(shape), jnp.uint32)
+    with jax.named_scope(L.RNG):
+        k = jax.random.fold_in(jax.random.fold_in(key, step), color)
+        return jax.random.bits(k, (2,) + tuple(shape), jnp.uint32)
 
 
 def update_color(quads_blocked: jax.Array, bits: jax.Array, beta: float,
@@ -64,9 +67,10 @@ def sweep(quads: jax.Array, key: jax.Array, step, *, beta: float,
     """One full sweep of [4, R, C] compact quads. Returns updated quads."""
     qb = _block_quads(quads, bs)
     blk = qb.shape[1:]
-    for color in (0, 1):
-        bits = color_bits(key, step, color, blk)
-        qb = update_color(qb, bits, beta, color, backend, rule=rule)
+    with jax.named_scope(L.SWEEP):
+        for color in (0, 1):
+            bits = color_bits(key, step, color, blk)
+            qb = update_color(qb, bits, beta, color, backend, rule=rule)
     return _unblock_quads(qb)
 
 
@@ -81,10 +85,11 @@ def run_sweeps(quads: jax.Array, key: jax.Array, *, n_sweeps: int, beta: float,
     blk = qb.shape[1:]
 
     def body(i, q):
-        for color in (0, 1):
-            bits = color_bits(key, i, color, blk)
-            q = update_color(q, bits, beta, color, backend, rule=rule)
-        return q
+        with jax.named_scope(L.SWEEP):
+            for color in (0, 1):
+                bits = color_bits(key, i, color, blk)
+                q = update_color(q, bits, beta, color, backend, rule=rule)
+            return q
 
     qb = jax.lax.fori_loop(0, n_sweeps, body, qb)
     return _unblock_quads(qb)

@@ -11,6 +11,8 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and with several pytest workers only
 the one given this file does.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -72,6 +74,22 @@ def test_kernel_compiles_for_v5e(topo, mosaic, backend, rule, side):
         compiled = step.lower(qb, bits).compile()
         assert "tpu_custom_call" in compiled.as_text()
         assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("backend,name", [
+    ("pallas", "checkerboard_update"),
+    ("pallas_lines", "checkerboard_update_lines")])
+def test_kernels_keep_their_names(topo, mosaic, backend, name):
+    """Each kernel's custom call carries its ``pallas_call`` name, which is
+    what a trace shows for it."""
+    one = SingleDeviceSharding(topo.devices[0])
+    m = 1024 // 2 // BS
+    qb = jax.ShapeDtypeStruct((4, m, m, BS, BS), jnp.bfloat16, sharding=one)
+    bits = jax.ShapeDtypeStruct((2, m, m, BS, BS), jnp.uint32, sharding=one)
+    step = jax.jit(lambda q, b: kops.update_color(q, b, 0.44, 0,
+                                                  backend=backend))
+    text = step.lower(qb, bits).compile().as_text()
+    assert re.search(rf"%{name}(\.\d+)? = .* custom-call\(", text)
 
 
 def test_table1_paper_chain_fits_one_chip(topo):
