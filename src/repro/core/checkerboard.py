@@ -6,9 +6,13 @@ Three implementations, all bitwise-comparable when fed the same uniforms:
                                  (``jnp.roll`` neighbour sums). Ground truth.
 * :func:`update_naive`         — paper Algorithm 1: blocked matmuls against the
                                  tridiagonal kernel ``K`` + colour mask ``M``.
-* :func:`update_color_compact` — paper Algorithm 2: compact parity quads,
+* :func:`update_color_blocked` — paper Algorithm 2: compact parity quads,
                                  matmuls against the bidiagonal kernel K-hat.
                                  ~3x less work (no wasted RNG / nn / mask).
+                                 Takes and returns a 4-tuple of blocked
+                                 quads; :func:`update_color_compact` and
+                                 :func:`sweep_compact` wrap it for
+                                 [4, R, C] quads.
 
 Site updates dispatch on :mod:`repro.core.update_rules` — ``accept``
 names a registry rule: ``exp`` (paper), ``lut`` (exact 5-entry table;
@@ -202,60 +206,82 @@ def nn_white(a, b, c, d, kh, edges=default_edges):
     return nn_b, nn_c
 
 
-def update_color_compact(quads: jax.Array, probs0: jax.Array,
-                         probs1: jax.Array, beta, color: int,
-                         block_size: int = L.MXU_BLOCK,
-                         accept: str = "lut", edges=default_edges,
-                         field: float = 0.0, return_stats: bool = False):
-    """Paper Algorithm 2: update one colour of the compact representation.
+def update_color_blocked(qb, p0: jax.Array, p1: jax.Array, beta,
+                         color: int, accept: str = "lut",
+                         edges=default_edges, field: float = 0.0,
+                         return_stats: bool = False):
+    """Paper Algorithm 2: update one colour of the blocked quads.
 
-    quads:  [4, R, C] parity sub-lattices.
-    probs0: [R, C] uniforms for the first quad of the colour (A if black, B else).
-    probs1: [R, C] uniforms for the second quad (D if black, C else).
+    qb:     4-tuple (A, B, C, D) of [mr, mc, bs, bs] blocked parity quads.
+    p0:     [mr, mc, bs, bs] uniforms for the first quad of the colour (A if
+            black, B else), blocked like the quads.
+    p1:     uniforms for the second quad (D if black, C else).
     edges:  halo provider (default: single-device torus rolls).
-    return_stats: also return ``(new0, new1, nn0, nn1)`` (blocked) — the
-        inputs the streaming measurement plane (:mod:`repro.core.measure`)
-        turns into the bond energy without recomputing neighbour sums.
+    return_stats: also return ``(new0, new1, nn0, nn1)`` — the inputs the
+        streaming measurement plane (:mod:`repro.core.measure`) turns into
+        the bond energy without recomputing neighbour sums.
+
+    The quads stay in the tuple layout, so a loop that carries them pays no
+    block, unblock or restack per colour.
     """
-    kh = L.kernel_compact(block_size, quads.dtype)
-    with jax.named_scope(L.LAYOUT):
-        a, b, c, d = (L.block(quads[i], block_size) for i in range(4))
+    a, b, c, d = qb
+    kh = L.kernel_compact(a.shape[-1], a.dtype)
     if color == 0:  # black: flip A and D
         nn0, nn1 = nn_black(a, b, c, d, kh, edges)
         s0, s1 = a, d
     else:           # white: flip B and C
         nn0, nn1 = nn_white(a, b, c, d, kh, edges)
         s0, s1 = b, c
-    p0 = L.block(probs0, block_size)
-    p1 = L.block(probs1, block_size)
+    with jax.named_scope(L.RNG):   # exact even where the draw fuses in
+        p0, p1 = (rules.uniforms_in(p, s0.dtype) for p in (p0, p1))
     new0 = _flip(s0, nn0.astype(s0.dtype), p0, beta, accept, field)
     new1 = _flip(s1, nn1.astype(s1.dtype), p1, beta, accept, field)
-    with jax.named_scope(L.LAYOUT):
-        if color == 0:
-            out = jnp.stack([L.unblock(new0), quads[1], quads[2],
-                             L.unblock(new1)])
-        else:
-            out = jnp.stack([quads[0], L.unblock(new0), L.unblock(new1),
-                             quads[3]])
+    out = (new0, b, c, new1) if color == 0 else (a, new0, new1, d)
     if return_stats:
         return out, (new0, new1, nn0, nn1)
     return out
+
+
+def sweep_blocked(qb, probs, beta, accept: str = "lut",
+                  edges=default_edges, field: float = 0.0) -> tuple:
+    """One full sweep (black then white) of the blocked 4-tuple. probs: 4
+    blocked uniform planes [black0, black1, white0, white1]."""
+    qb = update_color_blocked(qb, probs[0], probs[1], beta, 0, accept,
+                              edges, field)
+    return update_color_blocked(qb, probs[2], probs[3], beta, 1, accept,
+                                edges, field)
+
+
+def update_color_compact(quads: jax.Array, probs0: jax.Array,
+                         probs1: jax.Array, beta, color: int,
+                         block_size: int = L.MXU_BLOCK,
+                         accept: str = "lut", edges=default_edges,
+                         field: float = 0.0, return_stats: bool = False):
+    """:func:`update_color_blocked` on [4, R, C] quads and [R, C] uniforms:
+    blocks them, updates, and unblocks the colour's two quads.
+    ``return_stats`` gives the blocked ``(new0, new1, nn0, nn1)``."""
+    qb = L.block_quads(quads, block_size)
+    p0, p1 = L.block(probs0, block_size), L.block(probs1, block_size)
+    out, stats = update_color_blocked(qb, p0, p1, beta, color, accept,
+                                      edges, field, return_stats=True)
+    flipped = L.BLACK_QUADS if color == 0 else L.WHITE_QUADS
+    with jax.named_scope(L.LAYOUT):
+        out = jnp.stack([L.unblock(out[i]) if i in flipped else quads[i]
+                         for i in range(4)])
+    return (out, stats) if return_stats else out
 
 
 def sweep_compact(quads: jax.Array, probs: jax.Array, beta,
                   block_size: int = L.MXU_BLOCK,
                   accept: str = "lut", edges=default_edges,
                   field: float = 0.0) -> jax.Array:
-    """One full sweep (black then white). probs: [4, R, C] uniforms, laid out
-    as [black0, black1, white0, white1]."""
-    with jax.named_scope(L.LAYOUT):
-        p0, p1 = probs[0], probs[1]
-    quads = update_color_compact(quads, p0, p1, beta, 0,
-                                 block_size, accept, edges, field)
-    with jax.named_scope(L.LAYOUT):
-        p2, p3 = probs[2], probs[3]
-    return update_color_compact(quads, p2, p3, beta, 1,
-                                block_size, accept, edges, field)
+    """:func:`sweep_blocked` on [4, R, C] quads and [4, R, C] uniforms
+    (laid out as [black0, black1, white0, white1]): blocks once, sweeps,
+    unblocks once."""
+    qb = L.block_quads(quads, block_size)
+    pb = L.block_quads(probs, block_size)
+    qb = sweep_blocked(qb, pb, beta, accept, edges, field)
+    return L.unblock_quads(qb)
 
 
 def quad_probs_from_full(probs_black: jax.Array,

@@ -87,6 +87,18 @@ def unblock(xb: jax.Array) -> jax.Array:
         return xb.transpose(0, 2, 1, 3).reshape(mr * bs, mc * bs)
 
 
+def block_quads(quads, bs: int = MXU_BLOCK) -> tuple:
+    """[4, R, C] quads (or planes) -> 4-tuple of [R/bs, C/bs, bs, bs]."""
+    with jax.named_scope(LAYOUT):
+        return tuple(block(quads[i], bs) for i in range(4))
+
+
+def unblock_quads(qb) -> jax.Array:
+    """4-tuple of blocked quads -> [4, R, C]; inverse of :func:`block_quads`."""
+    with jax.named_scope(LAYOUT):
+        return jnp.stack([unblock(q) for q in qb])
+
+
 def kernel_naive(n: int, dtype=jnp.bfloat16) -> jax.Array:
     """Paper's K: tridiagonal, zero diagonal, ones on sub/super diagonals.
 

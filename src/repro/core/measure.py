@@ -25,8 +25,9 @@ Three consumers, one code path:
 * blocked quads on one device (``blocked_stats``, kernel-backend scans);
 * ``shard_map`` sub-lattices — pass ``axis_names`` and local sums are
   ``lax.psum``-reduced into exact global scalars;
-* the compact [4, R, C] sweep loop (``sweep_compact_measured``) which
-  reuses the white-update nn tensors at zero extra matmul cost.
+* the blocked-quad sweep (``sweep_blocked_measured``, and its [4, R, C]
+  wrapper ``sweep_compact_measured``) which reuses the white-update nn
+  tensors at zero extra matmul cost.
 
 :class:`Moments` accumulates running ``(|m|, E, m^2, m^4, E^2)`` sums with
 ``measure_every`` thinning inside compiled loops — the paper's Fig.-4
@@ -109,27 +110,35 @@ def blocked_stats(qb, n_spins: Optional[int] = None, kh=None,
     return m, e
 
 
+def sweep_blocked_measured(qb, probs, beta, accept: str = "lut",
+                           edges=cb.default_edges,
+                           field: float = 0.0) -> tuple:
+    """One full sweep of the blocked 4-tuple that also streams (m, E/spin)
+    — the measured twin of :func:`repro.core.checkerboard.sweep_blocked`,
+    bitwise-identical state evolution, zero extra matmuls for the energy (it
+    reuses the white half-update's nn tensors). probs: 4 blocked uniform
+    planes [black0, black1, white0, white1]."""
+    qb = cb.update_color_blocked(qb, probs[0], probs[1], beta, 0, accept,
+                                 edges, field)
+    qb, (new0, new1, nn0, nn1) = cb.update_color_blocked(
+        qb, probs[2], probs[3], beta, 1, accept, edges, field,
+        return_stats=True)
+    n_spins = sum(q.size for q in qb)
+    m = magnetization_mean(qb, n_spins)
+    e = bond_energy_from_nn(new0, new1, nn0, nn1, n_spins)
+    return qb, (m, e)
+
+
 def sweep_compact_measured(quads: jax.Array, probs: jax.Array, beta,
                            block_size: int = L.MXU_BLOCK,
                            accept: str = "lut", edges=cb.default_edges,
                            field: float = 0.0) -> tuple:
-    """One full compact sweep that also streams (m, E/spin) — the measured
-    twin of :func:`repro.core.checkerboard.sweep_compact`, bitwise-identical
-    state evolution, zero extra matmuls for the energy (it reuses the white
-    half-update's nn tensors)."""
-    with jax.named_scope(L.LAYOUT):
-        p0, p1 = probs[0], probs[1]
-    quads = cb.update_color_compact(quads, p0, p1, beta, 0,
-                                    block_size, accept, edges, field)
-    with jax.named_scope(L.LAYOUT):
-        p2, p3 = probs[2], probs[3]
-    quads, (new0, new1, nn0, nn1) = cb.update_color_compact(
-        quads, p2, p3, beta, 1, block_size, accept, edges,
-        field, return_stats=True)
-    n_spins = quads.size
-    m = magnetization_mean(quads, n_spins)
-    e = bond_energy_from_nn(new0, new1, nn0, nn1, n_spins)
-    return quads, (m, e)
+    """:func:`sweep_blocked_measured` on [4, R, C] quads and uniforms — the
+    measured twin of :func:`repro.core.checkerboard.sweep_compact`."""
+    qb = L.block_quads(quads, block_size)
+    pb = L.block_quads(probs, block_size)
+    qb, (m, e) = sweep_blocked_measured(qb, pb, beta, accept, edges, field)
+    return L.unblock_quads(qb), (m, e)
 
 
 # ---------------------------------------------------------------------------

@@ -7,9 +7,17 @@ Two compiled entry points:
 * :func:`run_sweeps`   — measurement-free `lax.fori_loop`; used for benchmarks
                          (paper Tables 1-2 measure pure sweep throughput).
 
-RNG: a single threefry key folded per (sweep, colour) so every uniform draw is
-counter-indexed — reproducible and independent of execution order, matching
-how the distributed sampler derives per-device streams.
+RNG: the chain key is folded once per sweep, and that sweep's uniforms are
+``jax.random.uniform(fold_in(key, step), (4, R, C))``: every draw is
+counter-indexed, reproducible and independent of execution order. The
+chains draw them straight into the blocked layout
+(:func:`sweep_probs_blocked`): under jax's partitionable threefry each
+value depends only on the key and its flat index, so hashing the flat
+indices of the blocked positions gives the same values with no relayout.
+
+Both loops carry the lattice as a 4-tuple of blocked quads
+(:func:`repro.core.checkerboard.sweep_blocked`), blocked once on entry and
+unblocked once on exit.
 """
 from __future__ import annotations
 
@@ -19,6 +27,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.extend.random import threefry2x32_p
 
 from repro.core import checkerboard as cb
 from repro.core import lattice as L
@@ -45,34 +56,102 @@ def sweep_probs(key: jax.Array, step, shape, dtype) -> jax.Array:
         return jax.random.uniform(k, (4,) + shape, dtype)
 
 
-def make_sweep_fn(cfg: ChainConfig):
-    dtype = jnp.dtype(cfg.prob_dtype)
+def _uniform_from_bits(bits: jax.Array, dtype) -> jax.Array:
+    """``jax.random.uniform``'s map of 32 threefry bits to [0, 1) in
+    ``dtype`` (a float of at most 32 bits): the top bits become the
+    mantissa of a float in [1, 2), less one."""
+    finfo = jnp.finfo(dtype)
+    nbits, nmant = finfo.bits, finfo.nmant
+    rng_bits = 8 if nmant < 8 else nbits
+    uint = {8: jnp.uint8, 16: jnp.uint16, 32: jnp.uint32}
+    bits = bits.astype(uint[rng_bits]).astype(uint[nbits])
+    one = np.array(1.0, dtype).view(uint[nbits])
+    float_bits = lax.shift_right_logical(
+        bits, jnp.asarray(rng_bits - nmant, uint[nbits])) | one
+    # x * (maxval - minval) + minval and the max with minval that jax.random
+    # applies for [0, 1) leave these values unchanged.
+    return lax.bitcast_convert_type(float_bits, dtype) - jnp.array(1., dtype)
 
-    def one_sweep(quads: jax.Array, key: jax.Array, step) -> jax.Array:
-        with jax.named_scope(L.SWEEP):
-            probs = sweep_probs(key, step, quads.shape[1:], dtype)
-            return cb.sweep_compact(quads, probs, cfg.beta, cfg.block_size,
-                                    cfg.accept, field=cfg.field)
 
-    return one_sweep
+def _counter_words(site: jax.Array, base: int):
+    """(hi, lo) uint32 words of the 64-bit counters ``base + site``."""
+    base_lo = jnp.uint32(base % 2 ** 32)
+    lo = site + base_lo
+    hi = jnp.uint32(base // 2 ** 32) + (lo < base_lo).astype(jnp.uint32)
+    return hi, lo
+
+
+def _blocked_counters(plane: int, shape, bs: int):
+    """(hi, lo) words of the flat index in [4, R, C] of every site of one
+    uniform plane, laid out [R/bs, C/bs, bs, bs]."""
+    r, c = shape
+    if r % bs or c % bs:
+        raise ValueError(f"{shape} not divisible by block {bs}")
+    if r * c >= 2 ** 32:
+        raise ValueError(f"a uniform plane of {shape} has 2**32 sites or "
+                         f"more; the blocked draw indexes them in 32 bits")
+    grid = (r // bs, c // bs, bs, bs)
+
+    def iota(d):
+        return lax.broadcasted_iota(jnp.uint32, grid, d)
+
+    site = ((iota(0) * bs + iota(2)) * jnp.uint32(c)
+            + iota(1) * bs + iota(3))
+    return _counter_words(site, plane * r * c)
+
+
+def _counter_draw_matches(key: jax.Array, dtype) -> bool:
+    """Whether :func:`_blocked_uniform` reproduces ``jax.random.uniform``:
+    the partitionable threefry, and a float of at most 32 bits."""
+    return (jax.config.jax_threefry_partitionable
+            and str(jax.random.key_impl(key)) == "threefry2x32"
+            and jnp.finfo(dtype).bits <= 32)
+
+
+def _blocked_uniform(key: jax.Array, plane: int, shape, bs: int, dtype):
+    """Plane ``plane`` of ``jax.random.uniform(key, (4,) + shape, dtype)``,
+    blocked: each value is threefry of the key and of its flat index."""
+    k1, k2 = jax.random.key_data(key)
+    hi, lo = _blocked_counters(plane, shape, bs)
+    bits1, bits2 = threefry2x32_p.bind(k1, k2, hi, lo)
+    return _uniform_from_bits(bits1 ^ bits2, dtype)
+
+
+def sweep_probs_blocked(key: jax.Array, step, shape, dtype,
+                        block_size: int = L.MXU_BLOCK) -> tuple:
+    """The uniforms of :func:`sweep_probs` as 4 blocked planes
+    [mr, mc, bs, bs] (black A, black D, white B, white C), drawn in that
+    layout. Where jax's draw could not be reproduced (another PRNG, the
+    non-partitionable threefry, a 64-bit float) it draws as
+    :func:`sweep_probs` and blocks."""
+    with jax.named_scope(L.RNG):
+        k = jax.random.fold_in(key, step)
+        if _counter_draw_matches(k, dtype):
+            return tuple(_blocked_uniform(k, q, shape, block_size, dtype)
+                         for q in range(4))
+        probs = jax.random.uniform(k, (4,) + shape, dtype)
+    return L.block_quads(probs, block_size)
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
 def _run_chain_impl(quads, key, cfg: ChainConfig):
     """Measured chain: per-sweep (m, E) stream from the white half-update's
     own nn sums (repro.core.measure) — the compiled loop never rebuilds the
-    full lattice (`from_quads`) or re-rolls neighbour sums."""
+    full lattice (`from_quads`) or re-rolls neighbour sums. The scan carries
+    the blocked 4-tuple: one block on entry, one unblock on exit."""
     pdt = jnp.dtype(cfg.prob_dtype)
+    shape = quads.shape[1:]
+    qb = L.block_quads(quads, cfg.block_size)
 
     def body(carry, step):
         with jax.named_scope(L.SWEEP):
-            probs = sweep_probs(key, step, carry.shape[1:], pdt)
-            return ms.sweep_compact_measured(carry, probs, cfg.beta,
-                                             cfg.block_size, cfg.accept,
-                                             field=cfg.field)
+            probs = sweep_probs_blocked(key, step, shape, pdt,
+                                        cfg.block_size)
+            return ms.sweep_blocked_measured(carry, probs, cfg.beta,
+                                             cfg.accept, field=cfg.field)
 
-    final, (m_t, e_t) = jax.lax.scan(body, quads, jnp.arange(cfg.n_sweeps))
-    return final, m_t, e_t
+    final, (m_t, e_t) = jax.lax.scan(body, qb, jnp.arange(cfg.n_sweeps))
+    return L.unblock_quads(final), m_t, e_t
 
 
 def run_chain(quads: jax.Array, key: jax.Array, cfg: ChainConfig):
@@ -82,12 +161,18 @@ def run_chain(quads: jax.Array, key: jax.Array, cfg: ChainConfig):
 
 @functools.partial(jax.jit, static_argnums=(2,))
 def _run_sweeps_impl(quads, key, cfg: ChainConfig):
-    one_sweep = make_sweep_fn(cfg)
+    pdt = jnp.dtype(cfg.prob_dtype)
+    shape = quads.shape[1:]
 
-    def body(i, q):
-        return one_sweep(q, key, i)
+    def body(step, qb):
+        with jax.named_scope(L.SWEEP):
+            probs = sweep_probs_blocked(key, step, shape, pdt,
+                                        cfg.block_size)
+            return cb.sweep_blocked(qb, probs, cfg.beta, cfg.accept,
+                                    field=cfg.field)
 
-    return jax.lax.fori_loop(0, cfg.n_sweeps, body, quads)
+    qb = L.block_quads(quads, cfg.block_size)
+    return L.unblock_quads(jax.lax.fori_loop(0, cfg.n_sweeps, body, qb))
 
 
 def run_sweeps(quads: jax.Array, key: jax.Array, cfg: ChainConfig):

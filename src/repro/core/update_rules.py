@@ -230,6 +230,25 @@ def metropolis_acceptance(nn: jax.Array, sigma: jax.Array, beta,
 # ---------------------------------------------------------------------------
 
 
+def uniforms_in(probs: jax.Array, dtype) -> jax.Array:
+    """The uniforms as ``flip_probs`` compares them: cast to ``dtype``, the
+    lattice dtype, rounding to nearest even as ``astype`` does; from f32 to
+    bf16 by integer ops on the bits.
+
+    A loop that fuses the draw into the compare needs this (the blocked
+    chain does, ``checkerboard.update_color_blocked``): the TPU compiler
+    keeps a fusion's bf16 intermediates in f32 and may drop the rounding of
+    an ``astype`` there, which changes the decisions near each acceptance
+    value. Integer rounding cannot be dropped. Values must be finite
+    (uniforms in [0, 1) are)."""
+    if probs.dtype == jnp.float32 and jnp.dtype(dtype) == jnp.bfloat16:
+        b = jax.lax.bitcast_convert_type(probs, jnp.uint32)
+        b = b + jnp.uint32(0x7FFF) + ((b >> 16) & jnp.uint32(1))
+        return jax.lax.bitcast_convert_type((b >> 16).astype(jnp.uint16),
+                                            jnp.bfloat16)
+    return probs.astype(dtype)
+
+
 def _as_uniforms(probs: jax.Array, dtype) -> jax.Array:
     """The uniforms cast to the acceptance dtype, under the ``rng`` scope:
     XLA fuses the draw into this cast, which ends the fusion, so the draw's
