@@ -1,4 +1,4 @@
-"""Plain reference for one chunk of checkerboard Metropolis sweeps.
+"""Plain reference for 2-D Ising checkerboard Metropolis on one chain.
 
 It redoes, from the program's own input lattice, what the timed path did in
 the last chunk of the window, and reports the numbers ``run.py`` compares:
@@ -7,7 +7,12 @@ the last chunk of the window, and reports the numbers ``run.py`` compares:
   reference's disagree (exact, limit 0);
 * ``moments_gap``: the largest gap between the program's streamed moments
   of that chunk (|m|, m^2, m^4, and E/2, each on a scale of at most 1) and
-  the reference's, whose per-sweep sums are exact integers.
+  the reference's, whose per-sweep sums are exact integers;
+* ``onsager_gap`` (over the window): the window's mean chunk |m| against
+  Onsager's exact value for the infinite lattice.
+
+:func:`validate` refuses a configuration whose dynamics or streams this
+file does not redo (see :data:`MODELLED`).
 
 The reference imports nothing of the program. It follows the published
 update (Yang et al., arXiv:1903.11714, Algorithm 2 on the four parity
@@ -38,6 +43,30 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
+
+NUMBERS = ("spins_differ", "moments_gap", "onsager_gap")
+
+# The configuration keys whose values this file redoes, with the value it
+# redoes; a key left out of a configuration takes the engine's default,
+# which is the value given here.
+MODELLED = {"model": "ising", "dims": 2, "algorithm": "metropolis",
+            "rule": "metropolis", "accept": "lut", "pipeline": "paper",
+            "ensemble": "independent", "betas": [], "field": 0.0,
+            "measure": True, "measure_every": 1}
+
+
+def validate(config: dict) -> None:
+    """Raise where ``config`` sets a key to a value this file does not
+    redo: another model, dimension, algorithm or acceptance rule, the
+    ``opt`` pipeline's other uniform stream, a replica ensemble, a field,
+    or moments thinned or not streamed."""
+    for key, want in MODELLED.items():
+        got = config.get(key, want)
+        if (list(got) if isinstance(got, (list, tuple)) else got) != want:
+            raise ValueError(
+                f"references/metropolis2d does not model {key}={got!r} of "
+                f"configuration {config.get('name')!r} (it redoes "
+                f"{key}={want!r})")
 
 
 def acceptance_table(beta: float, dtype) -> jax.Array:
@@ -159,10 +188,11 @@ def _chunk_mesh_fn(mesh, row_axis, col_axis, n_sweeps, beta):
     return jax.jit(mapped)
 
 
-def check_chunk(start, final, key, n_sweeps: int, beta: float,
+def check_chunk(config: dict, start, final, key, n_sweeps: int,
                 moments: dict) -> dict:
     """Redo one chunk from ``start`` and compare with the program's
     ``final`` lattice and its streamed ``moments`` of the chunk."""
+    beta = config["beta"]
     if start.ndim == 3:
         differ, spins, bonds = _chunk_single(
             start, final, key, n_sweeps=n_sweeps, beta=float(beta))
@@ -186,3 +216,12 @@ def onsager_m(beta: float) -> float:
     Yang): (1 - sinh(2 beta)^-4)^(1/8) below T_c, 0 above."""
     s = math.sinh(2.0 * beta) ** -4
     return (1.0 - s) ** 0.125 if s < 1.0 else 0.0
+
+
+def check_window(config: dict, chunk_moments: list) -> dict:
+    """The window's mean chunk |m| against Onsager's exact value."""
+    m_abs_sum = 0.0
+    for moments in chunk_moments:
+        m_abs_sum += moments["m_abs"]
+    return {"onsager_gap": abs(m_abs_sum / len(chunk_moments)
+                               - onsager_m(config["beta"]))}

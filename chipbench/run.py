@@ -5,25 +5,30 @@
         --seconds 20 --trace 0
 
 A cell is ``workloads/<name>.json``: the configuration it runs
-(``configs/<config>.json``, the fields of ``repro.api.EngineConfig`` and
-where they come from), its traffic (``traffic/<traffic>.json``), the chips
-it needs and the limits of its correctness check. Per-layer metrics are the
-modules in ``metrics/``, found by listing the directory. Adding a cell, a
-configuration, a traffic mix or a metric is adding files.
+(``configs/<config>.json``, the fields of ``repro.api.EngineConfig``, where
+they come from, and the ``reference`` that checks them), its traffic
+(``traffic/<traffic>.json``), the chips it needs and the limits of its
+correctness check. A configuration's ``reference`` names a module in
+``references/``; per-layer metrics are the modules in ``metrics/``, found
+by listing the directory. Adding a cell, a configuration, its reference, a
+traffic mix or a metric is adding files.
 
 A run:
 
-1. Set-up: refuse to run without a TPU holding the cell's chips; turn on
-   the persistent compilation cache inside the checkout; build the lattice
-   from ``--seed`` through ``IsingEngine.init`` and run chunk 0 (burn-in),
-   which compiles the one chunk program the window drives.
+1. Set-up: refuse a configuration that its reference does not model, or
+   a limit on a number the reference does not give; refuse to run without
+   a TPU holding the cell's chips; turn on the persistent compilation
+   cache inside the checkout; build the lattice from ``--seed`` through
+   ``IsingEngine.init`` and run chunk 0 (burn-in), which compiles the one
+   chunk program the window drives.
 2. Window: ``IsingEngine.run`` on chunk i = 1, 2, ... keyed
    ``fold_in(chain_key, i)``, each on the state the last one left, until
    ``--seconds`` have passed; it ends when the last chunk is back.
    ``flips_per_ns`` is global sites x sweeps over the window.
-3. Check: the plain reference (``reference.py``) redoes the window's last
-   chunk from the program's own input to it and compares lattice and
-   moments; the window's mean |m| is held against Onsager's exact value.
+3. Check: the configuration's plain reference
+   (``references/<reference>.py``) redoes the window's last chunk from the
+   program's own input to it (``check_chunk``) and reads the moments of
+   every chunk of the window (``check_window``).
 4. ``--trace 1``: the profiler records the first chunks of the window
    inside harness spans, and the line reports the per-layer metrics.
 
@@ -51,8 +56,8 @@ HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(HERE.parent / "src"))
 
-import reference  # noqa: E402
 import devtrace  # noqa: E402
+import scopes  # noqa: E402
 import work  # noqa: E402
 
 TRACE_CHUNKS = 2     # chunks the profiler records in a --trace 1 run
@@ -70,6 +75,7 @@ class Cell:
     traffic: dict
     chips: int
     limits: dict
+    root: Path = HERE
 
 
 def _read(kind: str, name: str, root: Path) -> dict:
@@ -81,19 +87,40 @@ def load_cell(name: str, root: Path = HERE) -> Cell:
     w = _read("workloads", name, root)
     return Cell(name=name, config=_read("configs", w["config"], root),
                 traffic=_read("traffic", w["traffic"], root),
-                chips=int(w["chips"]), limits=dict(w["limits"]))
+                chips=int(w["chips"]), limits=dict(w["limits"]),
+                root=root)
+
+
+def _load(path: Path):
+    """The module in ``path``, loaded once per process."""
+    name = f"chipbench_{path.parent.name}_{path.stem}"
+    mod = sys.modules.get(name)
+    if mod is None or Path(mod.__file__) != path:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[name] = mod
+    return mod
 
 
 def metric_modules(root: Path = HERE) -> dict:
     """Every per-layer metric reader in ``metrics/``, by file name."""
-    out = {}
-    for path in sorted((root / "metrics").glob("*.py")):
-        spec = importlib.util.spec_from_file_location(
-            f"chipbench_metric_{path.stem}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        out[path.stem] = mod
-    return out
+    return {path.stem: _load(path)
+            for path in sorted((root / "metrics").glob("*.py"))}
+
+
+def reference_of(cell: Cell):
+    """The reference module that the cell's configuration names, once it
+    has accepted the configuration and gives every number the cell holds
+    to a limit."""
+    name = cell.config["reference"]
+    ref = _load(cell.root / "references" / f"{name}.py")
+    ref.validate(cell.config)
+    missing = sorted(set(cell.limits) - set(ref.NUMBERS))
+    if missing:
+        raise ValueError(f"workload {cell.name!r} holds {missing} to limits; "
+                         f"references/{name} gives only {list(ref.NUMBERS)}")
+    return ref
 
 
 def peaks(kind: str, root: Path = HERE) -> dict:
@@ -152,6 +179,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     import jax
     import jax.numpy as jnp
 
+    ref = reference_of(cell)
     devices = require_devices(jax, cell.chips)[:cell.chips]
     enable_compile_cache(jax)
 
@@ -166,7 +194,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     del prev
 
     log_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if traced else None
-    chunks, m_abs_sum = 0, 0.0
+    chunk_moments = []
     t_start = time.perf_counter()
     setup_s = t_start - t0
     tracing = traced
@@ -175,7 +203,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         window_span = jax.profiler.TraceAnnotation("window")
         window_span.__enter__()
     while True:
-        i = chunks + 1
+        i = len(chunk_moments) + 1
         with jax.profiler.TraceAnnotation("chunk.copy"):
             prev = jnp.copy(state)
         key = jax.random.fold_in(k_chain, i)
@@ -183,10 +211,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
             res = engine.run(state, key)
         with jax.profiler.TraceAnnotation("chunk.sync"):
             state = jax.block_until_ready(res.state)
-            moments = res.moments
-            m_abs_sum += moments["m_abs"]
-        chunks = i
-        if tracing and chunks == TRACE_CHUNKS:
+            chunk_moments.append(res.moments)
+        if tracing and i == TRACE_CHUNKS:
             window_span.__exit__(None, None, None)
             jax.profiler.stop_trace()
             tracing = False
@@ -197,17 +223,17 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     last_key = key
     del res, engine
 
+    chunks = len(chunk_moments)
     sweeps = chunks * n
     sites = work.sites(cell.config)
     log(f"setup {setup_s:.3f} s, window {window_s:.3f} s, {chunks} chunks "
         f"of {n} sweeps, peak {memory_peak} B")
     t_check = time.perf_counter()
-    checks = reference.check_chunk(prev, state, last_key, n,
-                                   cell.config["beta"], moments)
+    checks = ref.check_chunk(cell.config, prev, state, last_key, n,
+                             chunk_moments[-1])
     log(f"reference check of the last chunk took "
         f"{time.perf_counter() - t_check:.3f} s")
-    checks["onsager_gap"] = abs(m_abs_sum / chunks
-                                - reference.onsager_m(cell.config["beta"]))
+    checks.update(ref.check_window(cell.config, chunk_moments))
     correct = all(checks[k] <= cell.limits[k] for k in cell.limits)
 
     dev = devices[0]
@@ -220,10 +246,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         events = devtrace.extract(log_dir)
         shutil.rmtree(log_dir, ignore_errors=True)
         tr = devtrace.Trace(events)
+        modules = metric_modules(cell.root)
         ctx = Context(trace=tr, cell=cell, sweeps=TRACE_CHUNKS * n,
-                      peaks=peaks(dev.device_kind))
+                      peaks=peaks(dev.device_kind),
+                      scope_names=scopes.declared(modules.values()))
         metrics = {}
-        for name, mod in metric_modules().items():
+        for name, mod in modules.items():
             value = mod.read(ctx)
             if value is not None:
                 metrics[name] = {"value": value, "unit": mod.UNIT}
@@ -247,11 +275,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
 @dataclasses.dataclass(frozen=True)
 class Context:
     """What a per-layer metric reader gets: the reduced trace of the
-    traced chunks, the cell, the sweeps traced and the chip's peaks."""
+    traced chunks, the cell, the sweeps traced, the chip's peaks and the
+    scopes that device time is charged to (the program's and those the
+    metric readers declare)."""
     trace: devtrace.Trace
     cell: Cell
     sweeps: int
     peaks: dict
+    scope_names: tuple = scopes.SCOPES
 
 
 def main(argv=None) -> int:

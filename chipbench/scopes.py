@@ -30,6 +30,10 @@ compilation cache's key, so that executable was compiled from the same
 module, scopes included.
 A program without the scopes, or without ``lower`` for the cell's scenario,
 gives no map, and the metrics that read it are silent.
+
+Besides :data:`SCOPES`, the program's own, a metric reader may declare the
+scope it reads as ``SCOPE = "<name>"`` (a dynamics of its own names its work
+so); time is then charged by all of these names (:func:`declared`).
 """
 from __future__ import annotations
 
@@ -50,11 +54,18 @@ COPIES = ("copy", "copy-start", "copy-done")
 _programs: dict = {}
 
 
-def innermost(op_name: str) -> str:
-    """The innermost scope of :data:`SCOPES` in an ``op_name`` path (the
-    first path where XLA joined several with ``;``)."""
+def declared(modules) -> tuple:
+    """:data:`SCOPES` and, after them, the ``SCOPE`` that any of the metric
+    reader ``modules`` declares."""
+    extra = {getattr(m, "SCOPE", None) for m in modules} - set(SCOPES)
+    return SCOPES + tuple(sorted(extra - {None}))
+
+
+def innermost(op_name: str, names: tuple = SCOPES) -> str:
+    """The innermost scope of ``names`` in an ``op_name`` path (the first
+    path where XLA joined several with ``;``)."""
     for part in reversed(op_name.split(";")[0].split("/")):
-        if part in SCOPES:
+        if part in names:
             return part
     return UNSCOPED
 
@@ -84,13 +95,14 @@ def _parse(hlo_text: str) -> tuple:
     return instrs, roots
 
 
-def scope_map(hlo_text: str) -> dict:
-    """{instruction name: innermost scope or ""} of a compiled HLO module."""
+def scope_map(hlo_text: str, names: tuple = SCOPES) -> dict:
+    """{instruction name: innermost scope of ``names`` or ""} of a compiled
+    HLO module."""
     instrs, roots = _parse(hlo_text)
     votes: dict = {}
     for comp, op_name, *_ in instrs.values():
-        if op_name is not None and innermost(op_name):
-            votes.setdefault(comp, Counter())[innermost(op_name)] += 1
+        if op_name is not None and innermost(op_name, names):
+            votes.setdefault(comp, Counter())[innermost(op_name, names)] += 1
     memo: dict = {}
 
     def scope(name: str, depth: int = 0):
@@ -99,12 +111,12 @@ def scope_map(hlo_text: str) -> dict:
         memo[name] = None                   # cycle guard
         comp, op_name, calls, refs, opcode = instrs[name]
         root_op = instrs[roots[calls]][1] if calls in roots else None
-        if root_op is not None and innermost(root_op):
-            found = innermost(root_op)      # a fusion: its root's scope
-        elif calls in votes:                # or that of most of its ops
+        if root_op is not None and innermost(root_op, names):
+            found = innermost(root_op, names)   # a fusion: its root's scope
+        elif calls in votes:                  # or that of most of its ops
             found = votes[calls].most_common(1)[0][0]
-        elif op_name is not None and innermost(op_name):
-            found = innermost(op_name)
+        elif op_name is not None and innermost(op_name, names):
+            found = innermost(op_name, names)
         elif opcode in COPIES:
             found = "layout"
         elif op_name is not None:
@@ -126,15 +138,15 @@ def scope_map(hlo_text: str) -> dict:
     return {name: scope(name) or UNSCOPED for name in instrs}
 
 
-def program_scopes(cell) -> dict | None:
-    """The scope map of ``cell``'s chunk program, compiled for this
+def program_scopes(cell, names: tuple = SCOPES) -> dict | None:
+    """The map to ``names`` of ``cell``'s chunk program, compiled for this
     process's TPU; None off a TPU, or where the program names no scope."""
-    if cell.name not in _programs:
-        _programs[cell.name] = _compile_scopes(cell)
-    return _programs[cell.name]
+    if (cell.name, names) not in _programs:
+        _programs[cell.name, names] = _compile_scopes(cell, names)
+    return _programs[cell.name, names]
 
 
-def _compile_scopes(cell) -> dict | None:
+def _compile_scopes(cell, names: tuple) -> dict | None:
     import jax
     import jax.numpy as jnp
 
@@ -153,7 +165,7 @@ def _compile_scopes(cell) -> dict | None:
         lowered = engine.lower(state, key)
     except ValueError:      # a scenario whose chunk program it cannot give
         return None
-    found = scope_map(_compile_anew(lowered))
+    found = scope_map(_compile_anew(lowered), names)
     return found if any(found.values()) else None
 
 
@@ -174,17 +186,17 @@ def _compile_anew(lowered) -> str:
         compilation_cache.reset_cache()
 
 
-def shares(trace, scope_of: dict) -> dict:
-    """{scope: % of device busy time} of the ops charged to each scope
-    (``""`` for the rest), mean over devices. An op's time is its duration
-    clipped to the window; ops of one device do not overlap, so the shares
-    sum to 100."""
+def shares(trace, scope_of: dict, names: tuple = SCOPES) -> dict:
+    """{scope of ``names``: % of device busy time} of the ops charged to
+    each scope (``""`` for the rest), mean over devices. An op's time is
+    its duration clipped to the window; ops of one device do not overlap,
+    so the shares sum to 100."""
     per_dev = []
     for dev in trace.devices():
         busy = trace.busy_ns(dev)
         if busy <= 0:
             continue
-        total = dict.fromkeys(SCOPES + (UNSCOPED,), 0.0)
+        total = dict.fromkeys(names + (UNSCOPED,), 0.0)
         for name, _, s, e in trace.ops[dev]:
             s, e = max(s, trace.start), min(e, trace.end)
             if s < e:
@@ -198,7 +210,7 @@ def shares(trace, scope_of: dict) -> dict:
 def share(ctx, scope: str):
     """The metric readers' entry: ``scope``'s share in the traced chunks,
     or None where the program gives no scope map."""
-    scope_of = program_scopes(ctx.cell)
+    scope_of = program_scopes(ctx.cell, ctx.scope_names)
     if scope_of is None:
         return None
-    return shares(ctx.trace, scope_of).get(scope)
+    return shares(ctx.trace, scope_of, ctx.scope_names).get(scope)

@@ -108,6 +108,21 @@ def test_scope_map_of_a_compiled_module():
     assert {k: got[k] for k in want} == want
 
 
+def test_a_declared_scope_is_charged_like_the_programs():
+    """A ``label`` scope that only a metric reader declares: the module
+    with ``flip`` renamed ``label`` maps as before, with ``label``."""
+    reader = type(sys)("label_share")
+    reader.SCOPE = "label"
+    names = scopes.declared([reader, scopes])
+    assert names == scopes.SCOPES + ("label",)
+    assert scopes.innermost("jit(f)/sweep/label/while/body/min", names) \
+        == "label"
+    assert scopes.innermost("jit(f)/sweep/label/while/body/min") == "sweep"
+    want = {k: "label" if v == "flip" else v
+            for k, v in scopes.scope_map(HLO).items()}
+    assert scopes.scope_map(HLO.replace("/flip/", "/label/"), names) == want
+
+
 def op(name: str, opcode: str) -> str:
     return f"%{name} = bf16[8]{{0}} {opcode}(bf16[8]{{0}} %p.1)"
 
@@ -173,6 +188,15 @@ def test_recorded_run_reduces_to_what_it_printed(path):
             assert mod.read(ctx) == pytest.approx(printed.get(name),
                                                   rel=1e-12), name
     assert sum(got.values()) == pytest.approx(100, abs=0.05)
+
+
+@pytest.mark.parametrize("path", SCOPED, ids=lambda p: p.name)
+def test_recorded_shares_hold_with_a_declared_scope(path):
+    rec, _, tr = _recorded(path)
+    before = scopes.shares(tr, rec["scopes"])
+    after = scopes.shares(tr, rec["scopes"], scopes.SCOPES + ("label",))
+    assert after == {**before, "label": 0.0}
+    assert before["layout"] > 0 and before["halo"] > 0
 
 
 @pytest.mark.parametrize("path", SCOPED, ids=lambda p: p.name)

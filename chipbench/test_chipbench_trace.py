@@ -8,6 +8,7 @@ must give the printed numbers.
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 import sys
 from pathlib import Path
@@ -120,6 +121,16 @@ def test_roofline_counts_the_configuration_work():
     ev["devices"] = {}
     assert run.metric_modules()["sweep_roofline"].read(
         ctx(ev, cell=cell)) is None
+
+
+def test_roofline_is_silent_for_a_cluster_sweep():
+    cell = run.load_cell("t1-20480.metropolis")
+    cell = dataclasses.replace(
+        cell, config={**cell.config, "algorithm": "swendsen_wang"})
+    ev = {"host": [["window", 0, 10**9]],
+          "devices": {"TPU:0": [[op("fusion", "fusion"), 0, 10**9]]}}
+    assert run.metric_modules()["sweep_roofline"].read(
+        ctx(ev, cell=cell, sweeps=100)) is None
 
 
 RECORDED = sorted(HERE.glob("testdata/*.events.json.gz"))

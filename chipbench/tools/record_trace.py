@@ -76,7 +76,9 @@ def summary(rec: dict) -> dict:
     spans: dict = {}
     for n, _, d, _ in rec["engine"]:
         spans.setdefault(n, []).append(d / 1e6)
-    return {"shares": scopes.shares(tr, scope_of),
+    declared = set(scope_of.values()) - set(scopes.SCOPES) - {scopes.UNSCOPED}
+    names = scopes.SCOPES + tuple(sorted(declared))
+    return {"shares": scopes.shares(tr, scope_of, names),
             "unknown_op_share": 100.0 * (1 - known / total) if total else None,
             "engine_idle_ms": [[ns / 1e6 for ns in dev] for dev in idle],
             "engine_span_ms": spans}
@@ -103,7 +105,8 @@ def main(argv=None) -> int:
     devtrace.extract = keep
     out = run.run_cell(cell, args.seed, args.seconds, traced=True,
                        t0=time.perf_counter())
-    scope_of = scopes.program_scopes(cell) or {}
+    scope_of = scopes.program_scopes(
+        cell, scopes.declared(run.metric_modules(cell.root).values())) or {}
     names = {devtrace.short_name(n) for ops in extra["devices"].values()
              for n, _, _ in ops}
     rec = dict(extra, scopes={n: scope_of[n] for n in sorted(names)

@@ -16,7 +16,10 @@ import ml_dtypes  # noqa: F401  (registers bfloat16 with numpy)
 
 
 def sites(engine: dict) -> int:
-    """Global lattice sites of a 2-D engine configuration."""
+    """Global lattice sites of an engine configuration: a size^3 cube in
+    3-D, a size x width torus (width 0 or absent: square) in 2-D."""
+    if engine.get("dims", 2) == 3:
+        return engine["size"] ** 3
     return engine["size"] * (engine.get("width") or engine["size"])
 
 
